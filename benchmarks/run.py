@@ -18,6 +18,8 @@ import sys
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 MODULES = [
     "fig1_breakdown",      # Fig 1: attention share of inference
     "fig3_similarity",     # Fig 3 + Fig 12: similarity distributions
@@ -248,6 +250,7 @@ def main() -> None:
                          "regression")
     ap.add_argument("--regress-tol", type=float, default=0.10)
     args = ap.parse_args()
+    enable_compile_cache()
     only = args.only.split(",") if args.only else None
 
     print("name,us_per_call,derived")

@@ -293,12 +293,13 @@ def _quick_smoke():
 
 def _hw_leg():
     """Real-hardware leg: the compiled (interpret=False) fused kernel on
-    TPU/GPU. Skips cleanly on CPU — the interpreter numbers are covered
-    by --quick and the XLA-form numbers by the main sweep."""
+    TPU/GPU. Fails on CPU: this leg's numbers mean nothing without an
+    accelerator (the interpreter is covered by --quick and the XLA form
+    by the main sweep)."""
     if jax.default_backend() == "cpu":
         print("serve_fastpath --hw: backend is cpu (no accelerator) — "
-              "skipping the compiled-kernel leg")
-        return 0
+              "the compiled-kernel leg needs a TPU or GPU")
+        return 1
     eng, corpus = built_engine(threshold=0.8, mode="select")
     toks = jnp.asarray(corpus.sample(BATCH)[0])
     old = (eng.mc.mode, eng.mc.kernel_impl, eng.mc.device_fast_path,
@@ -333,7 +334,7 @@ if __name__ == "__main__":
     ap.add_argument("--quick", action="store_true",
                     help="one interpret-Pallas kernel batch vs select")
     ap.add_argument("--hw", action="store_true",
-                    help="compiled-kernel leg on TPU/GPU (skips on CPU)")
+                    help="compiled-kernel leg on TPU/GPU (fails on CPU)")
     a = ap.parse_args()
     if a.quick:
         sys.exit(_quick_smoke())

@@ -330,8 +330,10 @@ print("SHARDBENCH", json.dumps(out))
 @functools.lru_cache(maxsize=1)
 def collect_sharded():
     """8-way mesh sharded-store leg, in a subprocess (the parent jax
-    already initialized with the default device count)."""
-    env = dict(os.environ, PYTHONPATH="src")
+    already initialized with the default device count). The child is
+    pinned to the CPU: its mesh is virtual, and on an accelerator host
+    the parent already holds the chip."""
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     out = subprocess.run([sys.executable, "-c", _SHARDED_CODE],
                          capture_output=True, text=True, env=env,

@@ -34,7 +34,12 @@ device path) perform the identical float32-multiply→float16-round
 sequence for ``int8``, so select/bucket/kernel modes consume
 bit-identical APMs regardless of which tier served them. ``lowrank``
 reconstructs through a matmul whose summation order may differ between
-numpy and XLA — parity holds within float tolerance, not bit-exactly.
+numpy and XLA — parity holds within one float16 ulp, not bit-exactly.
+
+Device form: ``DeviceDB`` keeps every float16 part in HBM as its int16
+bit pattern (``hbm_form``; the memo kernel reads the f16 arena, and
+Mosaic loads no float16), so ``decode_rows`` reads its float16 parts
+through ``as_f16`` and accepts either form.
 """
 from __future__ import annotations
 
@@ -43,6 +48,8 @@ from typing import Tuple
 
 import jax.numpy as jnp
 import numpy as np
+
+from repro.kernels.memo_attention.kernel import as_f16
 
 
 @dataclass(frozen=True)
@@ -55,6 +62,13 @@ class PartSpec:
     @property
     def entry_nbytes(self) -> int:
         return int(np.prod(self.shape)) * np.dtype(self.dtype).itemsize
+
+
+def hbm_form(part: np.ndarray) -> np.ndarray:
+    """The device-tier form of a host part: float16 as its int16 bit
+    pattern (same bytes, a numpy view), anything else unchanged."""
+    part = np.asarray(part)
+    return part.view(np.int16) if part.dtype == np.float16 else part
 
 
 def _quantize_rows(x: np.ndarray):
@@ -131,7 +145,7 @@ class F16Codec(ApmCodec):
         return np.asarray(parts[0])
 
     def decode_rows(self, parts):
-        return parts[0]
+        return as_f16(parts[0])
 
 
 class Int8Codec(ApmCodec):
@@ -157,7 +171,7 @@ class Int8Codec(ApmCodec):
     def decode_rows(self, parts):
         codes, scales = parts
         return (codes.astype(jnp.float32)
-                * scales.astype(jnp.float32)[..., None]
+                * as_f16(scales).astype(jnp.float32)[..., None]
                 ).astype(jnp.float16)
 
 
@@ -213,8 +227,8 @@ class LowRankCodec(ApmCodec):
 
     def decode_rows(self, parts):
         uq, us, vq, vs = parts
-        u = uq.astype(jnp.float32) * us.astype(jnp.float32)[..., None]
-        v = vq.astype(jnp.float32) * vs.astype(jnp.float32)[..., None]
+        u = uq.astype(jnp.float32) * as_f16(us).astype(jnp.float32)[..., None]
+        v = vq.astype(jnp.float32) * as_f16(vs).astype(jnp.float32)[..., None]
         return jnp.einsum("...qr,...kr->...qk", u, v).astype(jnp.float16)
 
 

@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.codec import ApmCodec, F16Codec, get_codec
+from repro.core.codec import ApmCodec, F16Codec, get_codec, hbm_form
 
 
 def pad_delta_pow2(slots: np.ndarray, values: Optional[np.ndarray] = None):
@@ -320,7 +320,9 @@ class DeviceDB:
     generation counter upstream decides when a delta suffices. Every
     host→device byte is tallied in ``transfer_bytes`` — at the codec's
     compressed width; the hot path consumes ``parts`` and dequantizes in
-    its own jit, so the f16 APMs never exist in HBM."""
+    its own jit, so the f16 APMs never exist in HBM. Float16 parts are
+    held as their int16 bit pattern (``codec.hbm_form``): the memo kernel
+    reads the f16 arena tile by tile, and Mosaic loads no float16."""
 
     def __init__(self, apms, capacity: Optional[int] = None, sharding=None,
                  codec: Optional[ApmCodec] = None):
@@ -338,6 +340,7 @@ class DeviceDB:
             if capacity > n:
                 pad = np.zeros((capacity - n,) + p.shape[1:], p.dtype)
                 p = np.concatenate([p, pad], 0)
+            p = hbm_form(p)
             parts.append(jax.device_put(p, sharding) if sharding is not None
                          else jnp.asarray(p))
         self.parts: Tuple[jnp.ndarray, ...] = tuple(parts)
@@ -356,11 +359,9 @@ class DeviceDB:
     @property
     def apms(self) -> jnp.ndarray:
         """The full arena, decoded. For the identity codec this is the
-        raw array (zero cost); for compressed codecs it MATERIALIZES the
-        decoded arena — tests/debugging only, never the hot path (which
-        gathers ``parts`` and dequantizes per batch)."""
-        if isinstance(self.codec, F16Codec):
-            return self.parts[0]
+        raw array viewed as float16 (zero cost); for compressed codecs it
+        MATERIALIZES the decoded arena — tests/debugging only, never the
+        hot path (which gathers ``parts`` and dequantizes per batch)."""
         return self.codec.decode_rows(self.parts)
 
     def update(self, slots, values) -> int:
@@ -382,8 +383,8 @@ class DeviceDB:
         slots_dev = jnp.asarray(slots)
         shipped = int(slots.size * 4)
         new_parts = []
-        for arr, p in zip(self.parts, parts):
-            p = jnp.asarray(np.asarray(p, arr.dtype))
+        for arr, spec, p in zip(self.parts, self.codec.parts, parts):
+            p = jnp.asarray(hbm_form(np.asarray(p, spec.dtype)))
             new_parts.append(arr.at[slots_dev].set(p))
             shipped += int(p.nbytes)
         self.parts = tuple(new_parts)
@@ -397,7 +398,7 @@ class DeviceDB:
 
     @property
     def dtype(self):
-        return self.parts[0].dtype
+        return self.codec.parts[0].dtype
 
     @property
     def entry_nbytes(self) -> int:

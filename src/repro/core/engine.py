@@ -26,6 +26,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.core.database import AttentionDB, DeviceDB
 from repro.core.embedding import Embedder, train_embedder
@@ -889,9 +890,8 @@ class MemoEngine:
                     from repro.kernels.memo_attention.ops import \
                         memo_attention
                     qq, kk, vv = attn_mod._qkv(lp["mix"], x, cfg, positions)
-                    blk = max(8, min(128, S))
                     kw = dict(causal=cfg.causal, window=cfg.sliding_window,
-                              block_q=blk, block_k=blk, impl=impl,
+                              impl=impl,
                               interpret=(interpret if impl == "pallas"
                                          else None))
                     if varlen:      # padded key positions mask per sequence
@@ -912,10 +912,24 @@ class MemoEngine:
                         # sharded path, whose arenas are position-
                         # indexed: decode the B gathered rows (not the
                         # DB) and feed them as a B-row database
-                        out = memo_attention(
-                            qq, kk, vv, gather_apm(),
-                            jnp.arange(B, dtype=jnp.int32),
-                            hit.astype(jnp.int32), **kw)
+                        ops = ((qq, kk, vv, gather_apm(),
+                                hit.astype(jnp.int32))
+                               + ((kw.pop("lengths"),) if varlen else ()))
+
+                        def attend(qq, kk, vv, rows, hit, *lens):
+                            return memo_attention(
+                                qq, kk, vv, rows,
+                                jnp.arange(B, dtype=jnp.int32), hit,
+                                lengths=lens[0] if lens else None, **kw)
+                        if sharded:
+                            # XLA cannot partition a Pallas call: run it
+                            # replicated on every device of the store
+                            # mesh, like the rest of the layer
+                            attend = jax.shard_map(
+                                attend, mesh=index.mesh,
+                                in_specs=(P(),) * len(ops), out_specs=P(),
+                                check_vma=False)
+                        out = attend(*ops)
                     y = jnp.einsum("bshe,hed->bsd", out, lp["mix"]["wo"])
                 elif nq == 1:
                     apm = gather_apm()
@@ -1697,10 +1711,8 @@ class MemoEngine:
                 from repro.kernels.memo_attention.ops import memo_attention
                 x = bb.norm_apply(lp["norm1"], h, cfg.norm)
                 q, k, v = attn_mod._qkv(lp["mix"], x, cfg, positions)
-                S = x.shape[1]
-                blk = max(8, min(128, S))
                 kw = dict(causal=cfg.causal, window=cfg.sliding_window,
-                          block_q=blk, block_k=blk, impl=impl,
+                          impl=impl,
                           interpret=(interpret if impl == "pallas"
                                      else None))
                 if varlen:

@@ -39,7 +39,7 @@ from typing import Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.codec import ApmCodec, PartSpec, _quantize_rows
+from repro.core.codec import ApmCodec, PartSpec, _quantize_rows, as_f16
 
 
 def _kv_mode(base_name: str, kv_codec: str,
@@ -168,15 +168,15 @@ class PrefillCodec(ApmCodec):
         contract as the APM codecs)."""
         kv = self._kv_parts(parts)
         if self.kv_mode == "f16":
-            return kv[0]
+            return as_f16(kv[0])
         if self.kv_mode == "int8":
             codes, scales = kv
             return (codes.astype(jnp.float32)
-                    * scales.astype(jnp.float32)[..., None]
+                    * as_f16(scales).astype(jnp.float32)[..., None]
                     ).astype(jnp.float16)
         uq, us, vq, vs = kv
-        u = uq.astype(jnp.float32) * us.astype(jnp.float32)[..., None]
-        v = vq.astype(jnp.float32) * vs.astype(jnp.float32)[..., None]
+        u = uq.astype(jnp.float32) * as_f16(us).astype(jnp.float32)[..., None]
+        v = vq.astype(jnp.float32) * as_f16(vs).astype(jnp.float32)[..., None]
         return jnp.einsum("...sr,...rd->...sd", u, v).astype(jnp.float16)
 
 

@@ -135,10 +135,7 @@ class MemoServer:
             raise RuntimeError("build() the engine before serving")
         if not engine._use_fast_path():
             raise RuntimeError("MemoServer drives the device fast path; "
-                               "use RuntimeSpec(mode='bucket')")
-        if engine.mc.mode == "kernel":
-            raise RuntimeError("variable-length serving supports bucket "
-                               "mode (the kernel path is fixed-length)")
+                               "use RuntimeSpec(mode='bucket' or 'kernel')")
         self.engine = engine
         s_max = engine.store.apm_shape[-1]
         self.buckets = tuple(sorted(int(b) for b in (

@@ -57,26 +57,18 @@ from repro.sharding.rules import memo_row_spec
 _ALL_GATHER = jax.lax.all_gather
 
 
-def _shard_map(body, mesh, in_specs, out_specs):
-    """Version-compat shard_map (jax>=0.5 top-level vs experimental)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def make_store_mesh(n_shards: Optional[int] = None,
                     axis: str = "store") -> Mesh:
-    """A 1-D mesh over the local devices for the sharded store. Requests
-    past ``jax.device_count()`` clamp (an 8-shard spec on a 1-device dev
-    box degrades to S=1 rather than failing); the 8-way CPU runs set
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before jax
-    import."""
+    """A 1-D mesh over the local devices for the sharded store. Asking
+    for more shards than ``jax.device_count()`` raises: a store that
+    quietly shrank would hold a fraction of the budget it was given. The
+    8-way CPU runs set ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
+    before jax import."""
     devs = np.asarray(jax.devices())
-    n = devs.size if n_shards is None else max(1, min(int(n_shards),
-                                                      int(devs.size)))
+    n = devs.size if n_shards is None else int(n_shards)
+    if not 1 <= n <= devs.size:
+        raise ValueError(f"{n} store shards requested but {devs.size} "
+                         f"{devs[0].platform} device(s) exist")
     return Mesh(devs[:n], (axis,))
 
 
@@ -102,8 +94,8 @@ def mesh_search(embs, queries, mesh, *, db_axis: str = "data"):
         cols = jnp.arange(q.shape[0])
         return mins[best, cols], idxs[best, cols]
 
-    smap = _shard_map(body, mesh, in_specs=(P(db_axis, None), P()),
-                      out_specs=(P(), P()))
+    smap = jax.shard_map(body, mesh=mesh, in_specs=(P(db_axis, None), P()),
+                         out_specs=(P(), P()), check_vma=False)
     return smap(embs, queries)
 
 
@@ -360,7 +352,8 @@ class ShardedDeviceIndex:
                     P(), P(), P(), P(), P(self.axis))
         assert len(in_specs) == n_in + 2
         out_specs = ((P(), P(), P()) if with_rows else (P(), P()))
-        smap = _shard_map(body, self.mesh, in_specs, out_specs)
+        smap = jax.shard_map(body, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
         return smap(table, norms, slot_at, cents, cnorms, owner, hot_t,
                     hnorms, hot_s, jnp.asarray(q, jnp.float32),
                     hot_parts, tuple(parts or ()))

@@ -33,6 +33,15 @@ int8 codes + per-row f16 scales (the ``int8`` APM codec); the kernel
 gathers the int8 tile (half the HBM→VMEM bytes) plus its (block_q,)
 scale sliver and dequantizes IN VMEM immediately before the APM·V
 matmul — the f16 APM never exists anywhere, on either memory level.
+The scales of the B gathered entries are taken out of the arena before
+the dispatch as a (B, H, 1, S) float32 operand: Mosaic refuses a
+``(1, 1, block_q)`` block of the (N, H, S) arena and loads no float16.
+
+Float16 DB: Mosaic on TPU v5e loads no float16 either, so an f16
+arena arrives as its int16 bit pattern (``DeviceDB`` keeps it that way
+in HBM, same bytes) and each gathered tile is decoded to float32 in
+VMEM with integer ops (``f16_bits_to_f32``) — exact, so hits replay the
+stored APM bit for bit.
 """
 from __future__ import annotations
 
@@ -46,9 +55,30 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
+def f16_bits_to_f32(bits):
+    """float16 bit patterns (int16) → the float32 values they encode,
+    with integer ops only (exact, subnormals and inf/nan included)."""
+    x = bits.astype(jnp.int32) & 0xFFFF
+    sign = (x >> 15) << 31
+    e = (x >> 10) & 0x1F
+    m = x & 0x3FF
+    e32 = jnp.where(e == 0x1F, 0xFF, e + 112)        # rebias 15 → 127
+    normal = jax.lax.bitcast_convert_type(sign | (e32 << 23) | (m << 13),
+                                          jnp.float32)
+    sub = m.astype(jnp.float32) * 2.0 ** -24         # e == 0: m · 2⁻²⁴
+    return jnp.where(e == 0, jnp.where(sign != 0, -sub, sub), normal)
+
+
+def as_f16(x):
+    """int16 float16-bit patterns → float16 (a view); other dtypes pass."""
+    if x.dtype == jnp.int16:
+        return jax.lax.bitcast_convert_type(x, jnp.float16)
+    return x
+
+
 def _memo_kernel(hit_idx_ref, hit_ref, len_ref, q_ref, k_ref, v_ref,
                  apm_ref, *rest, scale, causal, window, block_q, block_k,
-                 quantized=False):
+                 quantized=False, f16_bits=False):
     if quantized:      # static: the int8 variant carries a scale sliver
         sc_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -68,11 +98,13 @@ def _memo_kernel(hit_idx_ref, hit_ref, len_ref, q_ref, k_ref, v_ref,
 
     @pl.when(hit)
     def _memo_path():
-        apm = apm_ref[0, 0].astype(jnp.float32)          # (block_q, block_k)
+        apm = apm_ref[0, 0]                              # (block_q, block_k)
+        apm = (f16_bits_to_f32(apm) if f16_bits
+               else apm.astype(jnp.float32))
         if quantized:
             # fused dequant: int8 codes × per-row scale, in VMEM, right
             # before the APM·V matmul
-            apm = apm * sc_ref[0, 0].astype(jnp.float32)[:, None]
+            apm = apm * sc_ref[0, 0, 0][:, None]
         acc_scr[...] += jax.lax.dot_general(
             apm, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -114,13 +146,15 @@ def memo_attention_bhsd(q, k, v, db_apm, hit_idx, hit, *, lengths=None,
                         db_scales=None, causal=True, window=None,
                         block_q=128, block_k=128, interpret=False):
     """q: (B, H, S, d); k, v: (B, Hkv, S, d); db_apm: (N, H, S, S) —
-    the device-resident attention DB; hit_idx, hit: (B,) int32;
-    ``lengths`` (B,) int32 bounds the miss path's key mask per sequence
-    (None → every sequence is full-length S).
+    the device-resident attention DB (an int16 DB holds float16 bit
+    patterns); hit_idx, hit: (B,) int32; ``lengths`` (B,) int32 bounds
+    the miss path's key mask per sequence (None → every sequence is
+    full-length S).
 
-    ``db_scales`` (N, H, S) f16 switches the DB to the int8 codec:
-    ``db_apm`` holds int8 codes and each gathered tile is dequantized in
-    VMEM against its per-row scale sliver (fused-dequant gather).
+    ``db_scales`` (N, H, S) f16 (or its int16 bits) switches the DB to
+    the int8 codec: ``db_apm`` holds int8 codes and each gathered tile is
+    dequantized in VMEM against its per-row scale sliver (fused-dequant
+    gather).
 
     The hit flag conditions every index map (see module docstring): hit
     programs alias Q/K to one resident tile and stream only APM tiles;
@@ -137,12 +171,14 @@ def memo_attention_bhsd(q, k, v, db_apm, hit_idx, hit, *, lengths=None,
         "DB tiles must cover the (padded) sequence: pad/slice in ops"
     nq, nk = S // block_q, S // block_k
     quantized = db_scales is not None
+    f16_bits = db_apm.dtype == jnp.int16
     if lengths is None:
         lengths = jnp.full((B,), S, jnp.int32)
 
     kernel = functools.partial(
         _memo_kernel, scale=d ** -0.5, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, quantized=quantized)
+        block_q=block_q, block_k=block_k, quantized=quantized,
+        f16_bits=f16_bits)
 
     # Index maps — the aliasing core. A Pallas program whose index map
     # resolves to the same block as the previous grid step re-uses the
@@ -170,7 +206,7 @@ def memo_attention_bhsd(q, k, v, db_apm, hit_idx, hit, *, lengths=None,
 
     def sc_map(b, h, iq, ik, hit_idx, hit, lens):
         m = hit[b] == 1          # quantized misses move zero scale bytes
-        return (jnp.where(m, hit_idx[b], 0), jnp.where(m, h, 0),
+        return (jnp.where(m, b, 0), jnp.where(m, h, 0), 0,
                 jnp.where(m, iq, 0))
 
     in_specs = [
@@ -182,8 +218,10 @@ def memo_attention_bhsd(q, k, v, db_apm, hit_idx, hit, *, lengths=None,
     ]
     operands = [q, k, v, db_apm]
     if quantized:
-        in_specs.append(pl.BlockSpec((1, 1, block_q), sc_map))
-        operands.append(db_scales)
+        # the B gathered entries' scales, one (1, block_q) row per tile
+        sc = as_f16(jnp.take(db_scales, hit_idx, axis=0, mode="clip"))
+        in_specs.append(pl.BlockSpec((1, 1, 1, block_q), sc_map))
+        operands.append(sc.astype(jnp.float32)[:, :, None, :])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, H, nq, nk),

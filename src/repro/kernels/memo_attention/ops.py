@@ -19,11 +19,17 @@ unless ``interpret`` was passed explicitly, which pins the Pallas path
 (that is how the kernel tests keep testing the kernel).
 
 Ragged sequence lengths are handled HERE (the kernel asserts tile
-alignment): q/k/v and the DB tiles are zero-padded up to the block
-grid, and the padded key positions are masked through the per-sequence
-``lengths`` operand — seq lengths like 96 from varlen buckets no longer
-crash kernel mode. Misses never fetch DB tiles at all (the hit flag
-aliases the gather index map), so no clamp of ``hit_idx`` is needed.
+alignment): q/k/v are zero-padded up to the DB's own sequence length L
+(or, past L, to the block grid), and the padded key positions are
+masked through the per-sequence ``lengths`` operand. A bucket shorter
+than the arena therefore pads the small q/k/v, never the (N, H, L, L)
+DB: stored APMs are hard zeros past their entry's length, so the padded
+tile replays the same rows. Misses never fetch DB tiles at all (the hit
+flag aliases the gather index map), so no clamp of ``hit_idx`` is needed.
+
+A float16 DB is best passed as its int16 bit pattern, which is how
+``DeviceDB`` keeps it in HBM: Mosaic loads no float16, and the
+bitcast of a float16 operand here costs a copy of the whole DB on TPU.
 """
 from __future__ import annotations
 
@@ -33,7 +39,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.memo_attention.kernel import NEG_INF, memo_attention_bhsd
+from repro.kernels.memo_attention.kernel import (NEG_INF, as_f16,
+                                                 memo_attention_bhsd)
 
 
 def _pad_axis(x, axis, pad):
@@ -66,13 +73,16 @@ def _memo_attention_pallas(q, k, v, db_apm, db_scales, hit_idx, hit, lengths,
                            *, causal, window, block_q, block_k, interpret,
                            has_scales, has_lengths):
     B, S, H, dh = q.shape
-    bq = min(block_q, S)
-    bk = min(block_k, S)
-    Sp = -(-S // math.lcm(bq, bk)) * math.lcm(bq, bk)   # ragged → pad up
+    T = max(S, db_apm.shape[-1])          # run at the DB's own length
+    bq = min(block_q, T)
+    bk = min(block_k, T)
+    Sp = -(-T // math.lcm(bq, bk)) * math.lcm(bq, bk)   # ragged → pad up
     q = _pad_axis(q, 1, Sp - S)
     k = _pad_axis(k, 1, Sp - S)
     v = _pad_axis(v, 1, Sp - S)
-    db_apm = _fit_db(db_apm, Sp, 2)
+    if db_apm.dtype == jnp.float16:
+        db_apm = jax.lax.bitcast_convert_type(db_apm, jnp.int16)
+    db_apm = _fit_db(db_apm, Sp, 2)       # pads only past the DB's length
     if has_scales:
         db_scales = _fit_db(db_scales, Sp, 1)
     if not has_lengths:        # fixed length: mask exactly the padding
@@ -116,10 +126,10 @@ def _memo_attention_xla(q, k, v, db_apm, db_scales, hit_idx, hit, lengths, *,
     m = jnp.max(s, -1, keepdims=True)
     p = jnp.where(s <= NEG_INF * 0.5, 0.0, jnp.exp(s - m))
     p = p / jnp.maximum(jnp.sum(p, -1, keepdims=True), 1e-30)
-    apm = jnp.take(db_apm, hit_idx, axis=0).astype(jnp.float32)
+    apm = as_f16(jnp.take(db_apm, hit_idx, axis=0)).astype(jnp.float32)
     if has_scales:
-        apm = apm * jnp.take(db_scales, hit_idx,
-                             axis=0).astype(jnp.float32)[..., None]
+        apm = apm * as_f16(jnp.take(db_scales, hit_idx,
+                                    axis=0)).astype(jnp.float32)[..., None]
     apm = _fit_db(apm, S, 2)
     p = jnp.where((hit == 1)[:, None, None, None],
                   apm, p.reshape(B, H, S, S))
@@ -130,10 +140,10 @@ def _memo_attention_xla(q, k, v, db_apm, db_scales, hit_idx, hit, lengths, *,
 def memo_attention(q, k, v, db_apm, hit_idx, hit, *, db_scales=None,
                    lengths=None, causal=True, window=None, block_q=128,
                    block_k=128, interpret=None, impl=None):
-    """Model layout: q (B,S,H,dh), k/v (B,S,Hkv,dh), db_apm (N,H,L,L),
-    hit_idx/hit (B,). With ``db_scales`` (N,H,L) the DB is int8-quantized
-    (the ``int8`` APM codec) and tiles dequantize in VMEM — the
-    fused-dequant gather (DESIGN.md §2.6). ``lengths`` (B,) serves
+    """Model layout: q (B,S,H,dh), k/v (B,S,Hkv,dh), db_apm (N,H,L,L)
+    (int16 = float16 bit patterns), hit_idx/hit (B,). With ``db_scales``
+    (N,H,L) the DB is int8-quantized (the ``int8`` APM codec) and tiles
+    dequantize in VMEM — the fused-dequant gather (DESIGN.md §2.6). ``lengths`` (B,) serves
     variable-length batches: padded key positions are masked out of the
     miss path per sequence (hit APMs are already zero past their length).
 

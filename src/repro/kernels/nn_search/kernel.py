@@ -41,11 +41,11 @@ def _nn_kernel(q_ref, db_ref, *rest, block_q, block_n, n_total, has_norms):
     q = q_ref[...].astype(jnp.float32)               # (block_q, dim)
     d = db_ref[...].astype(jnp.float32)              # (block_n, dim)
     qn = jnp.sum(q * q, axis=-1, keepdims=True)
-    dn = (dn_ref[...].astype(jnp.float32) if has_norms
-          else jnp.sum(d * d, axis=-1))
+    dn = (dn_ref[...] if has_norms                   # (1, block_n)
+          else jnp.sum(d * d, axis=-1)[None, :])
     d2 = qn - 2.0 * jax.lax.dot_general(
         q, d, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) + dn[None, :]
+        preferred_element_type=jnp.float32) + dn
     npos = iN * block_n + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_n), 1)
     d2 = jnp.where(npos < n_total, d2, BIG)
@@ -58,8 +58,8 @@ def _nn_kernel(q_ref, db_ref, *rest, block_q, block_n, n_total, has_norms):
 
     @pl.when(iN == pl.num_programs(1) - 1)
     def _fin():
-        od_ref[...] = bd_scr[...]
-        oi_ref[...] = bi_scr[...]
+        od_ref[...] = bd_scr[...][:, None]
+        oi_ref[...] = bi_scr[...][:, None]
 
 
 def nn_search_kernel(q, db, *, db_norms=None, block_q=128, block_n=512,
@@ -91,19 +91,24 @@ def nn_search_kernel(q, db, *, db_norms=None, block_q=128, block_n=512,
     ]
     operands = [q, db]
     if has_norms:
-        in_specs.append(pl.BlockSpec((block_n,), lambda ib, iN: (iN,)))
-        operands.append(db_norms.astype(jnp.float32))
+        # a (1, N) row, not (N,): a 1-D block must match XLA's T(1024)
+        # vector tiling, while (1, block_n) meets the (8, 128) block rule
+        # for any block_n that is a multiple of 128 or all of N
+        in_specs.append(pl.BlockSpec((1, block_n), lambda ib, iN: (0, iN)))
+        operands.append(db_norms.astype(jnp.float32).reshape(1, -1))
+    # (B, 1) columns for the same reason: a (block_q,) block of a longer
+    # 1-D output would not match its vector tiling
     od, oi = pl.pallas_call(
         kernel,
         grid=(nb, nN),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((block_q,), lambda ib, iN: (ib,)),
-            pl.BlockSpec((block_q,), lambda ib, iN: (ib,)),
+            pl.BlockSpec((block_q, 1), lambda ib, iN: (ib, 0)),
+            pl.BlockSpec((block_q, 1), lambda ib, iN: (ib, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((q.shape[0],), jnp.float32),
-            jax.ShapeDtypeStruct((q.shape[0],), jnp.int32),
+            jax.ShapeDtypeStruct((q.shape[0], 1), jnp.float32),
+            jax.ShapeDtypeStruct((q.shape[0], 1), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q,), jnp.float32),
@@ -111,4 +116,4 @@ def nn_search_kernel(q, db, *, db_norms=None, block_q=128, block_n=512,
         ],
         interpret=interpret,
     )(*operands)
-    return od[:B], oi[:B]
+    return od[:B, 0], oi[:B, 0]

@@ -30,7 +30,6 @@ import jax
 from repro.configs import ARCH_IDS, INPUT_SHAPES, get_config
 from repro.configs.base import EncoderConfig
 from repro.launch.hlo_utils import collective_bytes, cost_summary
-from repro.launch.mesh import use_mesh
 from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import build_step
 
@@ -69,7 +68,7 @@ def lower_and_compile(arch, shape_name, mesh, *, cfg=None, layer_loop="scan",
         kind = built["meta"]["kind"]
         donate_argnums = (0, 1) if kind == "train" else (
             (2,) if kind == "decode" else ())
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jit_fn = jax.jit(built["fn"], in_shardings=built["in_shardings"],
                          out_shardings=built["out_shardings"],
                          donate_argnums=donate_argnums)
@@ -80,8 +79,6 @@ def lower_and_compile(arch, shape_name, mesh, *, cfg=None, layer_loop="scan",
     if verbose:
         print("  memory_analysis:", compiled.memory_analysis())
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):  # older jax: one dict per device
-            ca = ca[0] if ca else {}
         print("  cost_analysis: flops=%.3e bytes=%.3e" % (
             ca.get("flops", 0), ca.get("bytes accessed", 0)))
     return built, metrics

@@ -32,7 +32,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config
 from repro.configs.base import InputShape
 from repro.launch.dryrun import run_one
-from repro.launch.mesh import use_mesh
 from repro.launch.hlo_utils import collective_bytes, cost_summary
 from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import abstract_params
@@ -186,7 +185,7 @@ def pair3():
                "mesh": "pod256", "devices": 256, "tag": tag, "meta": meta,
                "status": "ok"}
         try:
-            with use_mesh(mesh):
+            with jax.set_mesh(mesh):
                 compiled = jax.jit(fn, in_shardings=in_sh).lower(
                     *args).compile()
             m = cost_summary(compiled)

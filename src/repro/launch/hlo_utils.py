@@ -59,8 +59,6 @@ def collective_bytes(hlo_text: str) -> Dict[str, int]:
 
 def cost_summary(compiled) -> Dict[str, float]:
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):   # older jax: one dict per device
-        ca = ca[0] if ca else {}
     ma = compiled.memory_analysis()
     out = {
         "flops": float(ca.get("flops", 0.0)),

@@ -4,11 +4,6 @@ A FUNCTION (not module-level state) so importing never touches jax device
 state. Single pod: (data=16, model=16) = 256 chips of TPU v5e; multi-pod:
 (pod=2, data=16, model=16) = 512 chips. The ``pod`` axis composes with
 ``data`` (logical dp = (pod, data)) for batch/FSDP shardings.
-
-Constructors paper over jax API drift: ``axis_types`` only exists on
-newer jax (older versions are Auto-only, which is what we pass anyway),
-and ``AbstractMesh`` changed its signature from one tuple of
-``(name, size)`` pairs to separate shape/name tuples.
 """
 from __future__ import annotations
 
@@ -16,20 +11,14 @@ import jax
 
 
 def _mesh(shape, axes):
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:       # older jax: meshes are implicitly Auto
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def abstract_mesh(**axes):
     """Device-free mesh for rule/spec math — tests and dry analysis."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(axes.values()), tuple(axes.keys()))
-    except TypeError:           # older signature: tuple of (name, size)
-        return AbstractMesh(tuple(axes.items()))
+    return AbstractMesh(tuple(axes.values()), tuple(axes.keys()))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -45,13 +34,3 @@ def dp_axes_of(mesh) -> tuple:
 def make_host_mesh(data: int = 1, model: int = 1):
     """Tiny mesh over however many (fake) host devices exist — tests."""
     return _mesh((data, model), ("data", "model"))
-
-
-def use_mesh(mesh):
-    """Ambient-mesh context. On jax without ``set_mesh`` this is a no-op:
-    every sharding we pass is a NamedSharding that carries its mesh."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is None:
-        import contextlib
-        return contextlib.nullcontext()
-    return set_mesh(mesh)

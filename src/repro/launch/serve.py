@@ -24,6 +24,7 @@ import numpy as np
 
 from repro.configs import get_config, get_reduced
 from repro.data import TemplateCorpus
+from repro.launch.compile_cache import enable_compile_cache
 from repro.memo import LEVELS, MemoSession, MemoSpec, MemoStats
 from repro.models import build_model
 from repro.train.checkpoint import load_checkpoint
@@ -190,7 +191,10 @@ def _serve_online(eng, corpus, args):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="bert_base")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the reduced config (--no-reduced: the "
+                         "published widths)")
     ap.add_argument("--requests", type=int, default=64)
     ap.add_argument("--batch", type=int, default=16)
     ap.add_argument("--seq", type=int, default=64)
@@ -271,8 +275,9 @@ def main():
                     help="warm-start from a saved session instead of "
                          "calibrating (skips build + embedder training)")
     args = ap.parse_args()
+    enable_compile_cache()
 
-    cfg = get_reduced(args.arch)
+    cfg = (get_reduced if args.reduced else get_config)(args.arch)
     if args.prefill:
         if args.online or args.varlen:
             raise SystemExit("--prefill is its own serving leg; drop "

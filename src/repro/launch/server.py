@@ -22,8 +22,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_reduced
+from repro.configs import get_config, get_reduced
 from repro.data import TemplateCorpus
+from repro.launch.compile_cache import enable_compile_cache
 from repro.memo import CHAOS_PRESETS, LEVELS, MemoSession, MemoSpec
 from repro.models import build_model
 
@@ -52,7 +53,7 @@ def make_workload(corpora, n_requests: int, rate: float, buckets,
 def build_session(args, seed: int = 0):
     """A freshly built session per A/B leg: both legs must start from the
     identical calibration store (serving mutates it)."""
-    cfg = get_reduced(args.arch)
+    cfg = (get_reduced if args.reduced else get_config)(args.arch)
     if not cfg.n_classes:
         cfg = cfg.replace(n_classes=4)
     model = build_model(cfg, layer_loop="unroll")
@@ -230,9 +231,10 @@ def run_fault_demo(args):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="bert_base")
-    ap.add_argument("--reduced", action="store_true", default=True,
-                    help="(always on — this launcher only serves reduced "
-                         "configs; kept for arg parity with launch.serve)")
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the reduced config (--no-reduced: the "
+                         "published widths)")
     ap.add_argument("--requests", type=int, default=96)
     ap.add_argument("--rate", type=float, default=None,
                     help="Poisson arrival rate, req/s (default: sized to "
@@ -263,6 +265,7 @@ def main():
                          "mid-trace, recover(), printing every health "
                          "transition (DESIGN.md §2.9)")
     args = ap.parse_args()
+    enable_compile_cache()
     args.bucket_list = (tuple(int(b) for b in args.buckets.split(","))
                         if args.buckets else (args.seq // 2, args.seq))
     if args.fault:

@@ -116,7 +116,7 @@ def _qkv(params, x, cfg, positions, use_rope=True):
 
 def gqa_apply(params, x, cfg, *, positions, mask_kind="causal",
               window=None, memo: Optional[Memo] = None, return_apm=False,
-              use_rope=True, attn_impl="xla", kpad=None):
+              use_rope=True, kpad=None):
     """Full-sequence GQA. x: (B,S,D) → (B,S,D).
 
     ``kpad``: optional (B, S) bool key-validity mask for padded
@@ -130,16 +130,8 @@ def gqa_apply(params, x, cfg, *, positions, mask_kind="causal",
     mask = make_mask(S, S, mask_kind, window)
     if kpad is not None:
         mask = mask[None] & kpad[:, None, :]
-    if attn_impl == "pallas_interpret" and memo is None and not return_apm \
-            and kpad is None:
-        from repro.kernels.flash_attention import ops as fa_ops
-        out = fa_ops.flash_attention(
-            q, k, v, causal=(mask_kind == "causal"), window=window,
-            interpret=True)
-        apm = None
-    else:
-        out, apm = _sdpa(qg, k, v, mask, dh ** -0.5, memo, return_apm)
-        out = out.reshape(B, S, H, dh)
+    out, apm = _sdpa(qg, k, v, mask, dh ** -0.5, memo, return_apm)
+    out = out.reshape(B, S, H, dh)
     y = jnp.einsum("bshe,hed->bsd", out, params["wo"])
     return y, apm
 
@@ -260,8 +252,7 @@ def _mla_qkr(params, x, cfg, positions):
 
 
 def mla_apply(params, x, cfg, *, positions, mask_kind="causal", window=None,
-              memo: Optional[Memo] = None, return_apm=False, attn_impl="xla",
-              kpad=None):
+              memo: Optional[Memo] = None, return_apm=False, kpad=None):
     B, S, _ = x.shape
     m, H = cfg.mla, cfg.n_heads
     scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5

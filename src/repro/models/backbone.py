@@ -111,7 +111,7 @@ def _layer_specs(cfg, layer_idx, kind):
 
 def _layer_apply(lp, h, cfg, kind, layer_idx, *, mode, positions, pos, cache,
                  memo=None, capture=False, mesh=None, dp_axes=("data",),
-                 window=None, attn_impl="xla", kpad=None):
+                 window=None, kpad=None):
     """Returns (h, new_cache, apm, aux_loss)."""
     mask_kind = "causal" if cfg.causal else "bidir"
     if cfg.act_shard_batch and mode == "full" and h.ndim == 3:
@@ -130,7 +130,7 @@ def _layer_apply(lp, h, cfg, kind, layer_idx, *, mode, positions, pos, cache,
             y, apm = attn.gqa_apply(lp["mix"], x, cfg, positions=positions,
                                     mask_kind=mask_kind, window=win,
                                     memo=memo, return_apm=capture,
-                                    attn_impl=attn_impl, kpad=kpad)
+                                    kpad=kpad)
             if mode == "prefill":
                 cache = attn.gqa_prefill_cache(
                     lp["mix"], x, cfg, positions, cache_len_from(cache))
@@ -143,15 +143,14 @@ def _layer_apply(lp, h, cfg, kind, layer_idx, *, mode, positions, pos, cache,
             y, apm = attn.mla_apply(lp["mix"], x, cfg, positions=positions,
                                     mask_kind=mask_kind, window=win,
                                     memo=memo, return_apm=capture,
-                                    attn_impl=attn_impl, kpad=kpad)
+                                    kpad=kpad)
             if mode == "prefill":
                 cache = attn.mla_prefill_cache(
                     lp["mix"], x, cfg, positions, cache_len_from(cache))
     elif kind == "rwkv6":
         y, cache_t = rwkv_mod.rwkv_time_apply(
             lp["mix"], x, cfg, None if mode == "full" else cache and
-            cache.get("time"),
-            impl=(attn_impl if mode == "full" else "scan"))
+            cache.get("time"))
         cache = dict(cache or {}, time=cache_t)
     elif kind == "rglru":
         y, cache_r = rglru_mod.rglru_apply(
@@ -295,7 +294,7 @@ def embed_tokens(params, tokens, cfg):
 def forward_hidden(params, h, cfg, *, mode="full", positions=None, pos=None,
                    caches=None, memo_plan=None, capture=False,
                    layer_loop="scan", mesh=None, dp_axes=("data",),
-                   window=None, attn_impl="xla", remat=False):
+                   window=None, remat=False):
     """Run all layers. Returns (h, new_caches, apms{layer_idx: apm}, aux)."""
     apms: Dict[int, Any] = {}
     aux_total = jnp.zeros((), jnp.float32)
@@ -321,7 +320,7 @@ def forward_hidden(params, h, cfg, *, mode="full", positions=None, pos=None,
                     positions=positions, pos=pos,
                     cache=gcaches.get(f"l{u}") if gcaches else None,
                     memo=memo, capture=cap, mesh=mesh, dp_axes=dp_axes,
-                    window=window, attn_impl=attn_impl)
+                    window=window)
                 out_caches[f"l{u}"] = c
                 aux_sum = aux_sum + aux
                 if apm is not None:

@@ -129,7 +129,7 @@ def encdec_specs(cfg):
 # ---------------------------------------------------------------------------
 
 def encode(params, frames, cfg, ecfg, *, capture=False, memo_plan=None,
-           layer_loop="scan", attn_impl="xla"):
+           layer_loop="scan"):
     """frames: (B, n_frames, d_enc) stub embeddings → (enc_h, apms)."""
     B, S, _ = frames.shape
     h = frames.astype(params["enc_pos"].dtype) + params["enc_pos"][None, :S]
@@ -140,8 +140,7 @@ def encode(params, frames, cfg, ecfg, *, capture=False, memo_plan=None,
         x = norm_apply(lp["norm1"], hh, cfg.norm)
         y, apm = attn.gqa_apply(lp["attn"], x, ecfg, positions=positions,
                                 mask_kind="bidir", memo=memo,
-                                return_apm=cap, use_rope=False,
-                                attn_impl=attn_impl)
+                                return_apm=cap, use_rope=False)
         hh = hh + y
         x = norm_apply(lp["norm2"], hh, cfg.norm)
         return hh + mlp_apply(lp["mlp"], x, cfg.act, cfg.glu), apm

@@ -19,12 +19,11 @@ from repro.models import encdec as ed
 
 class Model:
     def __init__(self, cfg, *, mesh=None, dp_axes=("data",),
-                 attn_impl="xla", layer_loop="scan", remat=False,
+                 layer_loop="scan", remat=False,
                  max_seq=4096):
         self.cfg = cfg
         self.mesh = mesh
         self.dp_axes = dp_axes
-        self.attn_impl = attn_impl
         self.layer_loop = layer_loop
         self.remat = remat
         self.max_seq = max_seq
@@ -56,7 +55,7 @@ class Model:
             enc_h, apms = ed.encode(
                 params, batch["frames"], self.cfg, self._ecfg,
                 capture=capture, memo_plan=memo_plan,
-                layer_loop=self.layer_loop, attn_impl=self.attn_impl)
+                layer_loop=self.layer_loop)
             h, _ = ed.decode_tokens(params, batch["tokens"], enc_h, self.cfg,
                                     mode="full", window=window,
                                     remat=self.remat,
@@ -68,8 +67,7 @@ class Model:
         h, _, apms, aux = bb.forward_hidden(
             params, h, self.cfg, mode="full", memo_plan=memo_plan,
             capture=capture, layer_loop=self.layer_loop, mesh=self.mesh,
-            dp_axes=self.dp_axes, window=window, attn_impl=self.attn_impl,
-            remat=self.remat)
+            dp_axes=self.dp_axes, window=window, remat=self.remat)
         return bb.logits_from_hidden(params, h, self.cfg), apms, aux
 
     # -- losses --------------------------------------------------------------
@@ -91,7 +89,7 @@ class Model:
         h, _, apms, _ = bb.forward_hidden(
             params, h, self.cfg, mode="full", memo_plan=memo_plan,
             capture=capture, layer_loop=self.layer_loop, mesh=self.mesh,
-            dp_axes=self.dp_axes, attn_impl=self.attn_impl)
+            dp_axes=self.dp_axes)
         logits = bb.classify_from_hidden(params, h, self.cfg)
         return (logits, apms) if capture else logits
 
@@ -118,8 +116,7 @@ class Model:
         caches = self.init_caches(B, cache_len, dtype, window=window)
         if self.is_encdec:
             enc_h, _ = ed.encode(params, batch["frames"], self.cfg,
-                                 self._ecfg, attn_impl=self.attn_impl,
-                                 layer_loop=self.layer_loop)
+                                 self._ecfg, layer_loop=self.layer_loop)
             h, caches = ed.decode_tokens(params, tokens, enc_h, self.cfg,
                                          mode="prefill", caches=caches,
                                          window=window,
@@ -130,7 +127,7 @@ class Model:
         h, caches, _, _ = bb.forward_hidden(
             params, h, self.cfg, mode="prefill", caches=caches,
             layer_loop=self.layer_loop, mesh=self.mesh,
-            dp_axes=self.dp_axes, window=window, attn_impl=self.attn_impl)
+            dp_axes=self.dp_axes, window=window)
         logits = bb.logits_from_hidden(params, h[:, -1:], self.cfg)
         return logits[:, 0], caches
 
@@ -147,7 +144,7 @@ class Model:
         h, caches, _, _ = bb.forward_hidden(
             params, h, self.cfg, mode="decode", caches=caches, pos=pos,
             layer_loop=self.layer_loop, mesh=self.mesh,
-            dp_axes=self.dp_axes, window=window, attn_impl=self.attn_impl)
+            dp_axes=self.dp_axes, window=window)
         logits = bb.logits_from_hidden(params, h, self.cfg)
         return logits[:, 0], caches
 

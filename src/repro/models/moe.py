@@ -29,24 +29,6 @@ from jax.sharding import PartitionSpec as P
 from repro.models.layers import dense_init
 
 
-def _shard_map(body, mesh, in_specs, out_specs):
-    """Version-compat shard_map (jax>=0.5 top-level vs experimental)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
-def _axis_size(name):
-    """Version-compat mapped-axis size (``lax.axis_size`` is newer jax;
-    ``psum(1, axis)`` folds to the same constant everywhere)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(name)
-    return lax.psum(1, name)
-
-
 def moe_init(key, cfg, dtype=jnp.float32):
     d, m = cfg.d_model, cfg.moe
     ks = jax.random.split(key, 4)
@@ -124,7 +106,7 @@ def _moe_chunk(x_c, wr, w_gate, w_up, w_down, *, cfg, ep_axis, tp_axis):
     (E_loc, D, F_loc) / (E_loc, F_loc, D)."""
     m = cfg.moe
     t, D = x_c.shape
-    ep = _axis_size(ep_axis)
+    ep = lax.axis_size(ep_axis)
     E_loc = w_gate.shape[0]
     _, weights, ids, aux = _router(x_c, wr, m.top_k)
     R = t * m.top_k
@@ -228,17 +210,17 @@ def moe_apply_ep(params, x, cfg, mesh, dp_axes=("data",), ep_axis="data",
     if T % dp_size != 0 or T < 4 * dp_size:
         body = partial(_moe_small_body, cfg=cfg, ep_axis=ep_axis,
                        tp_axis=tp_axis)
-        y, aux = _shard_map(
-            body, mesh, in_specs=w_specs + (P(),),
-            out_specs=(P(), P()),
+        y, aux = jax.shard_map(
+            body, mesh=mesh, in_specs=w_specs + (P(),),
+            out_specs=(P(), P()), check_vma=False,
         )(params["w_router"], params["w_gate"], params["w_up"],
           params["w_down"], xf)
         return y.reshape(shape), jnp.mean(aux)
     body = partial(_moe_body, cfg=cfg, ep_axis=ep_axis, tp_axis=tp_axis,
                    dp_axes=dp_axes)
-    y, aux = _shard_map(
-        body, mesh, in_specs=w_specs + (P(dp_axes, None),),
-        out_specs=(P(dp_axes, None), P()),
+    y, aux = jax.shard_map(
+        body, mesh=mesh, in_specs=w_specs + (P(dp_axes, None),),
+        out_specs=(P(dp_axes, None), P()), check_vma=False,
     )(params["w_router"], params["w_gate"], params["w_up"],
       params["w_down"], xf)
     return y.reshape(shape), aux

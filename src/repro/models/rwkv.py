@@ -90,11 +90,9 @@ def _groupnorm(x, scale, eps=1e-5):
     return ((xf - mu) * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
 
 
-def rwkv_time_apply(params, x, cfg, state=None, impl="scan"):
+def rwkv_time_apply(params, x, cfg, state=None):
     """Full-sequence time-mix. x: (B,S,D). state: {'s','x_prev'} or None.
-    ``impl='pallas_interpret'`` uses the chunked wkv kernel (fresh-state
-    sequences only — the chunked form starts from S=0). Returns
-    (y, new_state)."""
+    Returns (y, new_state)."""
     B, S, d = x.shape
     nh, N = d // cfg.rwkv_head_dim, cfg.rwkv_head_dim
     x_prev = (jnp.pad(x, ((0, 0), (1, 0), (0, 0)))[:, :-1] if state is None
@@ -109,17 +107,6 @@ def rwkv_time_apply(params, x, cfg, state=None, impl="scan"):
     w = jnp.exp(-jnp.exp(dec.astype(jnp.float32))).astype(x.dtype)
     w = w.reshape(B, S, nh, N)
     u = params["u"].reshape(nh, N)
-    if impl == "pallas_interpret" and state is None:
-        from repro.kernels.rwkv6.ops import wkv6_chunked
-        o = wkv6_chunked(r.astype(jnp.float32), k.astype(jnp.float32),
-                         v.astype(jnp.float32), w.astype(jnp.float32),
-                         u.astype(jnp.float32),
-                         chunk=min(32, max(8, S)), interpret=True)
-        o = o.astype(x.dtype)
-        o = _groupnorm(o, params["ln_scale"]).reshape(B, S, d) * g
-        # state output: recompute final state only (cheap rank-1 updates)
-        sT = None
-        return o @ params["wo"], {"s": sT, "x_prev": x[:, -1]}
     s0 = (jnp.zeros((B, nh, N, N), x.dtype) if state is None else state["s"])
     if cfg.act_shard_batch:
         # pin the scan operands/state to batch-sharding over both mesh

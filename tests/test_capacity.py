@@ -579,26 +579,27 @@ def test_write_through_then_demotion_is_free(tmp_path):
 @settings(max_examples=6, deadline=None)
 @given(codec_name=st.sampled_from(["f16", "int8", "lowrank"]),
        n=st.integers(2, 5), seed=st.integers(0, 10_000))
-def test_demote_promote_roundtrip_bit_identical(tmp_path, codec_name, n,
-                                                seed):
+def test_demote_promote_roundtrip_bit_identical(codec_name, n, seed):
     """Property (satellite): demote → promote round-trips every codec
-    part bit-identically, for all three codecs."""
-    d = tempfile.mkdtemp(dir=str(tmp_path))
-    rng = np.random.default_rng(seed)
-    s = MemoStore(APM, EMB, capacity=16, codec=codec_name,
-                  capacity_dir=os.path.join(d, "t"))
-    apms, embs = _entries(rng, n)
-    slots = s.admit(apms, embs)
-    before = [np.asarray(p).copy() for p in s.db.parts_at(slots)]
-    assert s.capacity_ok
-    s.evict(n)
-    assert s.live_count == 0 and s.stats.n_demoted == n
-    satisfied = s.promote_for(embs, threshold=0.5)
-    assert satisfied.all() and s.stats.n_promoted == n
-    _, idx = s.lookup(embs, 1)
-    after = s.db.parts_at(idx[:, 0])
-    for b, a in zip(before, after):
-        assert np.asarray(a).tobytes() == b.tobytes()
+    part bit-identically, for all three codecs. Each example gets its
+    own directory: hypothesis does not reset a function-scoped fixture
+    such as ``tmp_path`` between examples (and refuses the test)."""
+    with tempfile.TemporaryDirectory() as d:
+        rng = np.random.default_rng(seed)
+        s = MemoStore(APM, EMB, capacity=16, codec=codec_name,
+                      capacity_dir=os.path.join(d, "t"))
+        apms, embs = _entries(rng, n)
+        slots = s.admit(apms, embs)
+        before = [np.asarray(p).copy() for p in s.db.parts_at(slots)]
+        assert s.capacity_ok
+        s.evict(n)
+        assert s.live_count == 0 and s.stats.n_demoted == n
+        satisfied = s.promote_for(embs, threshold=0.5)
+        assert satisfied.all() and s.stats.n_promoted == n
+        _, idx = s.lookup(embs, 1)
+        after = s.db.parts_at(idx[:, 0])
+        for b, a in zip(before, after):
+            assert np.asarray(a).tobytes() == b.tobytes()
 
 
 def test_promote_quarantines_corrupt_disk_rows(tmp_path):

@@ -244,17 +244,13 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from contextlib import nullcontext
 from repro.core.shard import mesh_search
 from repro.kernels.nn_search.ref import nn_search_ref
-mesh_kw = {}
-if hasattr(jax.sharding, "AxisType"):
-    mesh_kw["axis_types"] = (jax.sharding.AxisType.Auto,)
-mesh = jax.make_mesh((8,), ("data",), **mesh_kw)
+mesh = jax.make_mesh((8,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 db = jax.random.normal(jax.random.PRNGKey(0), (256, 32))
 q = jax.random.normal(jax.random.PRNGKey(1), (17, 32))
-ctx = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else nullcontext()
-with ctx:
+with jax.set_mesh(mesh):
     dbs = jax.device_put(db, NamedSharding(mesh, P("data", None)))
     d, i = jax.jit(lambda a, b: mesh_search(a, b, mesh))(dbs, q)
 dr, ir = nn_search_ref(q, db)

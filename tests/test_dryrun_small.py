@@ -23,11 +23,7 @@ arch, shape = sys.argv[1], sys.argv[2]
 mesh = make_host_mesh(4, 2)
 built = build_step(arch, shape, mesh)
 assert built is not None
-# newer jax wants the ambient mesh set; the NamedShardings below carry
-# the mesh themselves, so older jax just lowers without the context
-import contextlib
-set_mesh = getattr(jax, "set_mesh", None)
-with (set_mesh(mesh) if set_mesh else contextlib.nullcontext()):
+with jax.set_mesh(mesh):
     lowered = jax.jit(built["fn"], in_shardings=built["in_shardings"],
                       out_shardings=built["out_shardings"]).lower(*built["args"])
     compiled = lowered.compile()

@@ -44,3 +44,21 @@ def test_dryrun_cli_single_combo(tmp_path):
     assert "-> ok" in out
     assert os.path.exists(
         os.path.join(tmp_path, "qwen2_1_5b_decode_32k_pod256.json"))
+
+
+def test_compile_cache_dir_rule(monkeypatch):
+    """One rule for the persistent compilation cache: the environment's
+    directory when set (and then nothing is set in code), else a fixed
+    directory inside the checkout."""
+    import jax
+    from repro.launch import compile_cache
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: calls.append((k, v)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+    assert calls == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    want = os.path.join(REPO, ".jax_cache")
+    assert compile_cache.enable_compile_cache() == want
+    assert calls == [("jax_compilation_cache_dir", want)]

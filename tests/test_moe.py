@@ -67,7 +67,6 @@ def test_ep_equivalence_multidevice():
     code = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import contextlib
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_reduced
@@ -82,8 +81,7 @@ cfg = get_reduced("dbrx_132b").replace(
 params = moe_mod.moe_init(jax.random.PRNGKey(1), cfg)
 x = jax.random.normal(jax.random.PRNGKey(2), (4, 16, cfg.d_model)) * 0.5
 y_ref, _ = moe_mod.moe_ref(params, x, cfg)
-set_mesh = getattr(jax, "set_mesh", None)
-with (set_mesh(mesh) if set_mesh else contextlib.nullcontext()):
+with jax.set_mesh(mesh):
     xs = jax.device_put(x, NamedSharding(mesh, P("data", None, None)))
     y_ep, _ = jax.jit(lambda p, xx: moe_mod.moe_apply_ep(p, xx, cfg, mesh))(params, xs)
 np.testing.assert_allclose(np.asarray(y_ref), np.asarray(y_ep),
@@ -109,10 +107,8 @@ def test_capacity_drops_are_bounded():
     y_ref, _ = moe_mod.moe_ref(params, x, cfg)
     # single-device mesh exercise of the EP code path
     from repro.launch.mesh import make_host_mesh
-    import contextlib
     mesh = make_host_mesh(1, 1)
-    set_mesh = getattr(jax, "set_mesh", None)
-    with (set_mesh(mesh) if set_mesh else contextlib.nullcontext()):
+    with jax.set_mesh(mesh):
         y_ep, _ = moe_mod.moe_apply_ep(params, x, cfg, mesh)
     assert np.isfinite(np.asarray(y_ep)).all()
     # dropped tokens produce zero expert output -> norm can only shrink
@@ -126,7 +122,6 @@ def test_ep_small_token_path_equivalence():
     code = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import contextlib
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_reduced
 from repro.configs.base import MoEConfig
@@ -140,8 +135,7 @@ params = moe_mod.moe_init(jax.random.PRNGKey(1), cfg)
 # T=6 tokens < 4*dp_size -> the small path triggers
 x = jax.random.normal(jax.random.PRNGKey(2), (6, cfg.d_model)) * 0.5
 y_ref, _ = moe_mod.moe_ref(params, x, cfg)
-set_mesh = getattr(jax, "set_mesh", None)
-with (set_mesh(mesh) if set_mesh else contextlib.nullcontext()):
+with jax.set_mesh(mesh):
     y_ep, _ = jax.jit(lambda p, xx: moe_mod.moe_apply_ep(p, xx, cfg, mesh))(params, x)
 np.testing.assert_allclose(np.asarray(y_ref), np.asarray(y_ep),
                            rtol=2e-4, atol=2e-4)

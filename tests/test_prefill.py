@@ -106,10 +106,17 @@ def test_prefill_codec_roundtrip(kv_mode):
     scale = float(np.abs(kv).max())
     tol = (1e-3 if kv_mode == "f16" else 0.05) * scale
     assert np.abs(got - kv).max() < tol
-    # device decode mirrors host decode op-for-op
+    # device decode mirrors host decode op-for-op: bit-identical for the
+    # elementwise codecs; lowrank reconstructs through a matmul whose
+    # float32 summation order differs between numpy and XLA, which can
+    # move the float16 result by one ulp
     dev = np.asarray(c.decode_kv_rows(tuple(jnp.asarray(p)
                                             for p in parts)))
-    np.testing.assert_array_equal(dev, np.asarray(c.decode_kv(parts)))
+    host = np.asarray(c.decode_kv(parts))
+    if kv_mode == "lowrank":
+        np.testing.assert_array_max_ulp(dev, host, maxulp=1)
+    else:
+        np.testing.assert_array_equal(dev, host)
 
 
 def test_prefill_codec_zero_fallback_and_shape_guard():

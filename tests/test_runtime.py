@@ -356,3 +356,32 @@ def test_pow2_buckets():
     assert pow2_buckets(64) == (16, 32, 64)
     assert pow2_buckets(32, n=2) == (16, 32)
     assert pow2_buckets(8) == (8,)
+
+
+@pytest.mark.parametrize("thr", [-1e9, 0.6, 1e9])
+def test_server_kernel_mode_matches_select(vl_engine, thr):
+    """MemoServer serves kernel mode (the memo_attention ``lengths``
+    operand carries variable length): each completion equals the select
+    reference on the padded batch the server built."""
+    eng, corpus = vl_engine
+    toks, lens = _varlen_batch(corpus, [SEQ, SEQ // 2, SEQ, SEQ // 2],
+                               SEQ)
+    mode, admit = eng.mc.mode, eng.mc.admit
+    eng.mc.mode, eng.mc.admit = "kernel", False
+    try:
+        server = MemoServer(eng, buckets=(SEQ,), max_batch=4,
+                            batch_quantum=4, async_maintenance=False)
+        for i, ln in enumerate(lens):
+            server.submit(toks[i, :ln])
+        eng.mc.threshold = thr
+        comps = sorted(server.step(flush=True), key=lambda c: c.rid)
+        server.close()
+        eng.mc.mode = "select"
+        ref, _ = eng.infer({"tokens": jnp.asarray(toks), "lengths": lens,
+                            "n_valid": len(lens)})
+    finally:
+        eng.mc.mode, eng.mc.admit, eng.mc.threshold = mode, admit, 0.6
+    assert len(comps) == len(lens)
+    for i, c in enumerate(comps):
+        np.testing.assert_allclose(c.logits, np.asarray(ref)[i],
+                                   rtol=2e-3, atol=2e-3)

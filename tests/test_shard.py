@@ -403,3 +403,13 @@ def test_eight_way_mesh_subprocess():
                          capture_output=True, text=True, env=env,
                          cwd=REPO, timeout=560)
     assert "SHARD8-OK" in out.stdout, out.stderr[-3000:]
+
+
+def test_make_store_mesh_refuses_more_shards_than_devices():
+    """Asking for more shards than devices raises instead of quietly
+    shrinking the store to the devices that exist."""
+    from repro.core.shard import make_store_mesh
+    n = jax.device_count()
+    assert make_store_mesh(n).shape["store"] == n
+    with pytest.raises(ValueError, match="store shards requested"):
+        make_store_mesh(n + 1)
