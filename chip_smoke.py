@@ -49,6 +49,9 @@ PRECISION_SLACK = 4.0
 BUCKETS = (64, 128)
 SEQ = 128
 BATCH = 8
+N_REQUESTS = 32      # per serving pass, cut to the buckets in turn
+DECODE_STEPS = 4     # greedy steps from each served gpt2 prefill cache
+N_SHARDS = 4         # --four-chip: one store shard per chip
 
 
 class Checks:
@@ -122,13 +125,12 @@ def check_steps(chk, eng, tag):
     chk.expect(not bad, f"{tag}: steps without tpu_custom_call: {bad}")
 
 
-def check_engine(chk, eng, tag, on_tpu):
+def check_engine(chk, eng, tag):
     log(f"[{tag}] kernel impl {eng._kernel_impl}, "
         f"interpret {eng._interpret}")
-    if on_tpu:
-        chk.expect(eng._kernel_impl == "pallas",
-                   f"{tag}: kernel impl {eng._kernel_impl!r} != 'pallas'")
-        chk.expect(not eng._interpret, f"{tag}: Pallas runs interpreted")
+    chk.expect(eng._kernel_impl == "pallas",
+               f"{tag}: kernel impl {eng._kernel_impl!r} != 'pallas'")
+    chk.expect(not eng._interpret, f"{tag}: Pallas runs interpreted")
 
 
 def check_health(chk, server, tag):
@@ -154,7 +156,8 @@ def pad_batch(reqs, bucket):
             "n_valid": len(reqs)}
 
 
-def serve(sess, reqs, *, buckets, prefill=False, async_maintenance=True):
+def serve(sess, reqs, buckets=BUCKETS, *, prefill=False,
+          async_maintenance=True):
     """Submit ``reqs`` to a fresh MemoServer and drain it. Returns
     (server, completions in submission order)."""
     server = sess.serve(buckets=buckets, max_batch=BATCH,
@@ -173,12 +176,12 @@ def serve(sess, reqs, *, buckets, prefill=False, async_maintenance=True):
     return server, comps
 
 
-def traffic(corpus, rng, n, buckets):
+def traffic(corpus, rng, n):
     """``n`` requests from the template corpus, cut to the bucket
     lengths in turn (a hit needs an entry of the request's own length,
     so the shorter bucket hits only after admission)."""
     toks = corpus.sample(n, rng)[0]
-    return [toks[i, : buckets[i % len(buckets)]] for i in range(n)]
+    return [toks[i, : BUCKETS[i % len(BUCKETS)]] for i in range(n)]
 
 
 def session(cfg, spec, corpus, rng, *, seed, calib_batches):
@@ -199,7 +202,7 @@ def session(cfg, spec, corpus, rng, *, seed, calib_batches):
     return sess, calib, levels
 
 
-def served_vs_reference(chk, sess, reqs, thr, tag, *, buckets):
+def served_vs_reference(chk, sess, reqs, thr, tag):
     """Serve ``reqs`` at ``thr`` (admission off, synchronous) and compare
     each completion with the reference on the same padded batch: select
     for memoized thresholds, the memo-off model when ``thr`` forces
@@ -207,11 +210,10 @@ def served_vs_reference(chk, sess, reqs, thr, tag, *, buckets):
     import numpy as np
     eng = sess.engine
     sess.spec.threshold = thr
-    server, comps = serve(sess, reqs, buckets=buckets,
-                          async_maintenance=False)
+    server, comps = serve(sess, reqs, async_maintenance=False)
     check_health(chk, server, tag)
     worst = 0.0
-    for b in buckets:
+    for b in BUCKETS:
         rows = [(r, c) for r, c in zip(reqs, comps) if c.bucket == b]
         if not rows:
             continue
@@ -236,9 +238,7 @@ def served_vs_reference(chk, sess, reqs, thr, tag, *, buckets):
     return server.stats.memo_rate
 
 
-def bert_phase(chk, cfg, codec, *, seed, on_tpu, calib_batches=4,
-               n_requests=32, spec_overrides=None, buckets=BUCKETS,
-               seq=SEQ):
+def bert_phase(chk, cfg, codec, *, seed):
     """bert_base in kernel mode through MemoServer for one codec."""
     import jax
     import numpy as np
@@ -248,25 +248,24 @@ def bert_phase(chk, cfg, codec, *, seed, on_tpu, calib_batches=4,
     tag = f"bert/{codec}"
     t0 = time.perf_counter()
     rng = np.random.default_rng(seed)
-    corpus = TemplateCorpus(vocab=cfg.vocab, seq_len=seq, seed=seed)
+    corpus = TemplateCorpus(vocab=cfg.vocab, seq_len=SEQ, seed=seed)
     spec = MemoSpec.flat(mode="kernel", apm_codec=codec, admit=True,
-                         device_slack=2.0, **(spec_overrides or {}))
+                         device_slack=2.0)
     sess, _, levels = session(cfg, spec, corpus, rng, seed=seed,
-                              calib_batches=calib_batches)
+                              calib_batches=4)
     eng = sess.engine
     store = sess.store
     log(f"[{tag}] built in {time.perf_counter() - t0:.1f}s: "
         f"{len(store.db)} entries x {store.entry_nbytes} B, "
         f"threshold {levels['aggressive']:.4f} (aggressive)")
-    check_engine(chk, eng, tag, on_tpu)
+    check_engine(chk, eng, tag)
 
     # the main path: admission on, maintenance off-thread
     for label, thr in (("autotuned", levels["aggressive"]),
                        ("forced-hit", -1e6)):
         t1 = time.perf_counter()
         sess.spec.threshold = thr
-        server, comps = serve(sess, traffic(corpus, rng, n_requests,
-                                            buckets), buckets=buckets)
+        server, comps = serve(sess, traffic(corpus, rng, N_REQUESTS))
         rate = server.stats.memo_rate
         finite = all(np.all(np.isfinite(c.logits)) for c in comps)
         log(f"[{tag}] {label}: {len(comps)} requests in "
@@ -274,32 +273,30 @@ def bert_phase(chk, cfg, codec, *, seed, on_tpu, calib_batches=4,
             f"admitted {server.stats.n_admitted}, live "
             f"{store.live_count}")
         check_health(chk, server, f"{tag} {label}")
-        chk.expect(len(comps) == n_requests,
-                   f"{tag} {label}: {len(comps)}/{n_requests} completed")
+        chk.expect(len(comps) == N_REQUESTS,
+                   f"{tag} {label}: {len(comps)}/{N_REQUESTS} completed")
         chk.expect(finite, f"{tag} {label}: non-finite logits")
         if label == "forced-hit":
             chk.expect(rate > 0, f"{tag} forced-hit: zero hit rate")
-    if on_tpu:
-        check_steps(chk, eng, tag)
+    check_steps(chk, eng, tag)
 
     # parity with the store frozen: both legs under HIGHEST
     sess.spec.admit = False
-    reqs = traffic(corpus, rng, 2 * BATCH, buckets)
+    reqs = traffic(corpus, rng, 2 * BATCH)
     with jax.default_matmul_precision("highest"):
         for label, thr in (("autotuned", levels["aggressive"]),
                            ("forced-hit", -1e6), ("all-miss", 1e6)):
             rate = served_vs_reference(chk, sess, reqs, thr,
-                                       f"{tag} {label}", buckets=buckets)
+                                       f"{tag} {label}")
             if label == "forced-hit":
                 chk.expect(rate > 0, f"{tag} parity forced-hit: no hits")
         ref = {b: eng.infer(pad_batch([r for r in reqs if r.size == b], b),
-                            use_memo=False)[0] for b in buckets}
+                            use_memo=False)[0] for b in BUCKETS}
     # default precision, all-miss: bounded by the plain model's own
     # default-vs-highest gap on the same batch
     sess.spec.threshold = 1e6
-    server, comps = serve(sess, reqs, buckets=buckets,
-                          async_maintenance=False)
-    for b in buckets:
+    server, comps = serve(sess, reqs, async_maintenance=False)
+    for b in BUCKETS:
         rows = [(r, c) for r, c in zip(reqs, comps) if c.bucket == b]
         batch = pad_batch([r for r, _ in rows], b)
         plain = np.asarray(eng.infer(batch, use_memo=False)[0])
@@ -316,8 +313,7 @@ def bert_phase(chk, cfg, codec, *, seed, on_tpu, calib_batches=4,
     log(f"[{tag}] phase {time.perf_counter() - t0:.1f}s")
 
 
-def gpt2_phase(chk, cfg, *, seed, on_tpu, calib_batches=4, decode_steps=4,
-               spec_overrides=None, seq=SEQ):
+def gpt2_phase(chk, cfg, *, seed):
     """gpt2_small memoized prefill through MemoServer, then greedy decode
     from the served caches against prefill_exact's."""
     import jax
@@ -329,21 +325,20 @@ def gpt2_phase(chk, cfg, *, seed, on_tpu, calib_batches=4, decode_steps=4,
     tag = "gpt2/prefill"
     t0 = time.perf_counter()
     rng = np.random.default_rng(seed)
-    corpus = TemplateCorpus(vocab=cfg.vocab, seq_len=seq, seed=seed)
+    corpus = TemplateCorpus(vocab=cfg.vocab, seq_len=SEQ, seed=seed)
     spec = MemoSpec.flat(mode="bucket", apm_codec="f16", admit=False,
-                         device_slack=2.0, prefill_enabled=True,
-                         **(spec_overrides or {}))
+                         device_slack=2.0, prefill_enabled=True)
     # calibrated under HIGHEST, so a replayed prompt finds its own entry
     # and that entry holds the float32 reference's APM and K/V
     with jax.default_matmul_precision("highest"):
         sess, calib, levels = session(cfg, spec, corpus, rng, seed=seed,
-                                      calib_batches=calib_batches)
+                                      calib_batches=4)
     eng = sess.engine
     model = eng.model
     log(f"[{tag}] built in {time.perf_counter() - t0:.1f}s: "
         f"{len(sess.store.db)} entries x {sess.store.entry_nbytes} B, "
         f"threshold {levels['aggressive']:.4f} (aggressive)")
-    check_engine(chk, eng, tag, on_tpu)
+    check_engine(chk, eng, tag)
 
     # parity on replays of calibration prompts with every layer forced
     # to hit: each hit replays the prompt's own stored APM + K/V, so the
@@ -353,7 +348,7 @@ def gpt2_phase(chk, cfg, *, seed, on_tpu, calib_batches=4, decode_steps=4,
     replay = list(np.asarray(calib[0]["tokens"]))
     with jax.default_matmul_precision("highest"):
         sess.spec.threshold = -1e6
-        server, comps = serve(sess, replay, buckets=(seq,), prefill=True,
+        server, comps = serve(sess, replay, (SEQ,), prefill=True,
                               async_maintenance=False)
         check_health(chk, server, f"{tag} parity")
         rate = server.stats.memo_rate
@@ -369,7 +364,7 @@ def gpt2_phase(chk, cfg, *, seed, on_tpu, calib_batches=4, decode_steps=4,
             chk.expect(d <= PARITY_RTOL * scale,
                        f"{tag} prefill logits: {d:.3e}")
             lm, cm = jnp.asarray(c.logits)[None], c.caches
-            for step in range(decode_steps):
+            for step in range(DECODE_STEPS):
                 te = jnp.argmax(le, -1).reshape(1, 1)
                 agree += int(jnp.argmax(lm, -1)[0] == te[0, 0])
                 total += 1
@@ -383,7 +378,7 @@ def gpt2_phase(chk, cfg, *, seed, on_tpu, calib_batches=4, decode_steps=4,
                            f"{tag} decode step {step}: {d:.3e}")
     log(f"[parity] {tag}: hit rate {rate:.3f}; prefill max|d| {d_pf:.3e} "
         f"(bound {bound_pf:.3e}), decode max|d| {d_dec:.3e} (bound "
-        f"{bound_dec:.3e}) over {decode_steps} steps, greedy agreement "
+        f"{bound_dec:.3e}) over {DECODE_STEPS} steps, greedy agreement "
         f"{agree}/{total}")
 
     # the main path at default precision: replays and novel prompts,
@@ -391,7 +386,7 @@ def gpt2_phase(chk, cfg, *, seed, on_tpu, calib_batches=4, decode_steps=4,
     sess.spec.threshold = levels["aggressive"]
     sess.spec.admit = True
     reqs = replay + list(corpus.sample(BATCH, rng)[0])
-    server, comps = serve(sess, reqs, buckets=(seq,), prefill=True)
+    server, comps = serve(sess, reqs, (SEQ,), prefill=True)
     rate = server.stats.memo_rate
     log(f"[{tag}] {len(comps)} prefill requests, hit rate {rate:.3f}, "
         f"admitted {server.stats.n_admitted}")
@@ -401,14 +396,12 @@ def gpt2_phase(chk, cfg, *, seed, on_tpu, calib_batches=4, decode_steps=4,
         c.caches is not None and np.all(np.isfinite(c.logits))
         for c in comps), f"{tag}: a completion lacks finite logits or "
                          f"a decode cache")
-    if on_tpu:
-        check_steps(chk, eng, tag)
+    check_steps(chk, eng, tag)
     log(f"[{tag}] phase {time.perf_counter() - t0:.1f}s")
 
 
-def sharded_store_leg(chk, *, seed, n_shards, apm_shape, dim=128,
-                      n_entries=2048, n_templates=64, rounds=8):
-    """A ShardedMemoStore over ``n_shards`` devices against a one-device
+def sharded_store_leg(chk, cfg, *, seed):
+    """A ShardedMemoStore over N_SHARDS devices against a one-device
     store at equal total budget: the same admission stream (the budget
     holds 3/4 of it, so both evict), the same queries."""
     import jax
@@ -416,6 +409,9 @@ def sharded_store_leg(chk, *, seed, n_shards, apm_shape, dim=128,
     import numpy as np
     from repro.core.shard import ShardedMemoStore
     from repro.core.store import MemoStore
+
+    dim, n_entries, n_templates, rounds = 128, 2048, 64, 8
+    apm_shape = (cfg.n_heads, SEQ, SEQ)
 
     rng = np.random.default_rng(seed)
     templates = (rng.normal(0, 1.0, (n_templates, dim)) * 4.0).astype(
@@ -429,7 +425,7 @@ def sharded_store_leg(chk, *, seed, n_shards, apm_shape, dim=128,
     def build(sharded):
         kw = dict(index_kind="exact", codec="int8", capacity=256,
                   budget_bytes=budget)
-        s = (ShardedMemoStore(apm_shape, dim, n_shards=n_shards, **kw)
+        s = (ShardedMemoStore(apm_shape, dim, n_shards=N_SHARDS, **kw)
              if sharded else
              MemoStore(apm_shape, dim, device_index_kind="flat", **kw))
         arng = np.random.default_rng(seed + 1)   # identical payloads
@@ -482,7 +478,7 @@ def sharded_store_leg(chk, *, seed, n_shards, apm_shape, dim=128,
     hit_gap = abs(single_rate - sh_rate)
     log(f"[shard] store: {n_entries} entries of {apm_shape} int8, budget "
         f"{budget / 1e6:.1f} MB total; hit rate one device "
-        f"{single_rate:.3f}, {n_shards} shards {sh_rate:.3f}, hit gap "
+        f"{single_rate:.3f}, {N_SHARDS} shards {sh_rate:.3f}, hit gap "
         f"{hit_gap:.3f}; occupancy {st['occupancy']} (imbalance "
         f"{st['imbalance']:.2f}x); payload parity {single_par and sh_par}; "
         f"{time.perf_counter() - t0:.1f}s")
@@ -493,8 +489,7 @@ def sharded_store_leg(chk, *, seed, n_shards, apm_shape, dim=128,
     chk.expect(sh_rate > 0, "shard store: zero hit rate")
 
 
-def sharded_engine_leg(chk, cfg, *, seed, n_shards, on_tpu,
-                       calib_batches=2, spec_overrides=None, seq=SEQ):
+def sharded_engine_leg(chk, cfg, *, seed):
     """bert_base in kernel mode over the sharded store: served logits
     against the select reference, both under HIGHEST."""
     import jax
@@ -503,19 +498,19 @@ def sharded_engine_leg(chk, cfg, *, seed, n_shards, on_tpu,
     from repro.data import TemplateCorpus
     from repro.memo import MemoSpec
 
-    tag = f"shard/bert x{n_shards}"
+    tag = f"shard/bert x{N_SHARDS}"
     t0 = time.perf_counter()
     rng = np.random.default_rng(seed)
-    corpus = TemplateCorpus(vocab=cfg.vocab, seq_len=seq, seed=seed)
-    spec = MemoSpec.flat(mode="kernel", apm_codec="int8", shards=n_shards,
-                         device_slack=2.0, **(spec_overrides or {}))
+    corpus = TemplateCorpus(vocab=cfg.vocab, seq_len=SEQ, seed=seed)
+    spec = MemoSpec.flat(mode="kernel", apm_codec="int8", shards=N_SHARDS,
+                         device_slack=2.0)
     sess, _, levels = session(cfg, spec, corpus, rng, seed=seed,
-                              calib_batches=calib_batches)
+                              calib_batches=2)
     eng = sess.engine
     log(f"[{tag}] built in {time.perf_counter() - t0:.1f}s: "
         f"{len(sess.store.db)} entries, occupancy "
         f"{sess.store.shard_stats()['occupancy']}")
-    check_engine(chk, eng, tag, on_tpu)
+    check_engine(chk, eng, tag)
     batch = {"tokens": jnp.asarray(corpus.sample(BATCH, rng)[0])}
     with jax.default_matmul_precision("highest"):
         for label, thr in (("autotuned", levels["aggressive"]),
@@ -554,9 +549,8 @@ def main(argv=None):
         print(f"chip_smoke: JAX finds no TPU (platform {dev.platform!r})",
               file=sys.stderr)
         return 2
-    want = 4 if args.four_chip else 1
-    if len(devs) < want:
-        print(f"chip_smoke: --four-chip needs 4 devices, found "
+    if args.four_chip and len(devs) < N_SHARDS:
+        print(f"chip_smoke: --four-chip needs {N_SHARDS} devices, found "
               f"{len(devs)}", file=sys.stderr)
         return 2
 
@@ -568,18 +562,14 @@ def main(argv=None):
         f"jax {jax.__version__}; compile cache {cache}")
     chk = Checks()
     t0 = time.perf_counter()
+    bert = get_config("bert_base")
     if args.four_chip:
-        bert = get_config("bert_base")
-        sharded_store_leg(chk, seed=args.seed, n_shards=4,
-                          apm_shape=(bert.n_heads, SEQ, SEQ))
-        sharded_engine_leg(chk, bert, seed=args.seed, n_shards=4,
-                           on_tpu=True)
+        sharded_store_leg(chk, bert, seed=args.seed)
+        sharded_engine_leg(chk, bert, seed=args.seed)
     else:
-        bert = get_config("bert_base")
         for codec in ("int8", "f16"):
-            bert_phase(chk, bert, codec, seed=args.seed, on_tpu=True)
-        gpt2_phase(chk, get_config("gpt2_small"), seed=args.seed,
-                   on_tpu=True)
+            bert_phase(chk, bert, codec, seed=args.seed)
+        gpt2_phase(chk, get_config("gpt2_small"), seed=args.seed)
     log(f"[device] total {time.perf_counter() - t0:.1f}s, "
         f"peak_bytes_in_use {peak_bytes()}")
     if chk.failed:

@@ -36,20 +36,20 @@ bit-identical APMs regardless of which tier served them. ``lowrank``
 reconstructs through a matmul whose summation order may differ between
 numpy and XLA — parity holds within one float16 ulp, not bit-exactly.
 
-Device form: ``DeviceDB`` keeps every float16 part in HBM as its int16
-bit pattern (``hbm_form``; the memo kernel reads the f16 arena, and
-Mosaic loads no float16), so ``decode_rows`` reads its float16 parts
-through ``as_f16`` and accepts either form.
+Device form: every device arena (``DeviceDB`` and the sharded
+``ShardedDeviceDB``) keeps each float16 part in HBM as its int16 bit
+pattern (``hbm_form``; the memo kernel reads the f16 arena, and Mosaic
+loads no float16), so ``decode_rows`` reads its float16 parts through
+``as_f16`` and accepts either form.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
-
-from repro.kernels.memo_attention.kernel import as_f16
 
 
 @dataclass(frozen=True)
@@ -69,6 +69,14 @@ def hbm_form(part: np.ndarray) -> np.ndarray:
     pattern (same bytes, a numpy view), anything else unchanged."""
     part = np.asarray(part)
     return part.view(np.int16) if part.dtype == np.float16 else part
+
+
+def as_f16(x):
+    """The inverse view on device: int16 float16-bit patterns → float16;
+    other dtypes pass unchanged."""
+    if x.dtype == jnp.int16:
+        return jax.lax.bitcast_convert_type(x, jnp.float16)
+    return x
 
 
 def _quantize_rows(x: np.ndarray):

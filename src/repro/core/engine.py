@@ -1727,7 +1727,8 @@ class MemoEngine:
                     rows = tuple(jnp.take(p, hit_idx, axis=0)
                                  for p in db_parts)
                     out = memo_attention(
-                        q, k, v, store.codec.decode_rows(rows),
+                        q, k, v,
+                        store.codec.decode_rows(rows).astype(jnp.float32),
                         jnp.arange(h.shape[0], dtype=jnp.int32), hit, **kw)
                 y = jnp.einsum("bshe,hed->bsd", out, lp["mix"]["wo"])
                 return self._chan_tail(lp, h + y, li)

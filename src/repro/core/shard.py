@@ -44,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.core.codec import hbm_form
 from repro.core.database import pad_delta_parts, pad_delta_pow2
 from repro.core.faults import MemoStoreError
 from repro.core.index import TOMBSTONE, _kmeans
@@ -116,7 +117,8 @@ class ShardedDeviceDB:
     jit, ``update`` scatter deltas, ``transfer_bytes``), but rows are
     device POSITIONS (shard*M + row), not host slot ids — the sharded
     index returns each winner's codec rows from the combine, so the
-    engine never indexes these arenas by slot."""
+    engine never indexes these arenas by slot. Float16 parts are held as
+    their int16 bit pattern (``codec.hbm_form``), as in ``DeviceDB``."""
 
     def __init__(self, host_parts: Sequence[np.ndarray], mesh: Mesh,
                  axis: str, codec=None):
@@ -125,6 +127,7 @@ class ShardedDeviceDB:
         self.axis = axis
         parts = []
         for p in host_parts:
+            p = hbm_form(p)
             sh = NamedSharding(mesh, memo_row_spec(mesh, p.ndim, axis=axis,
                                                    shape=p.shape))
             parts.append(jax.device_put(p, sh))
@@ -155,8 +158,8 @@ class ShardedDeviceDB:
         pos_dev = jnp.asarray(pos)
         shipped = int(pos.size * 8)
         new_parts = []
-        for arr, p in zip(self.parts, parts):
-            p = jnp.asarray(np.asarray(p, arr.dtype))
+        for arr, spec, p in zip(self.parts, self.codec.parts, parts):
+            p = jnp.asarray(hbm_form(np.asarray(p, spec.dtype)))
             new_parts.append(arr.at[pos_dev].set(p))
             shipped += int(p.nbytes)
         self.parts = tuple(new_parts)
@@ -244,7 +247,7 @@ class ShardedDeviceIndex:
         bytes shipped."""
         self._hot_table = jnp.asarray(np.asarray(table, np.float32))
         self._hot_slots = jnp.asarray(np.asarray(slots, np.int32))
-        self._hot_parts = tuple(jnp.asarray(p) for p in parts)
+        self._hot_parts = tuple(jnp.asarray(hbm_form(p)) for p in parts)
         shipped = int(self._hot_table.nbytes + self._hot_slots.nbytes
                       + sum(int(p.nbytes) for p in self._hot_parts))
         self.transfer_bytes += shipped

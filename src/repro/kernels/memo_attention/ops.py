@@ -27,9 +27,9 @@ DB: stored APMs are hard zeros past their entry's length, so the padded
 tile replays the same rows. Misses never fetch DB tiles at all (the hit
 flag aliases the gather index map), so no clamp of ``hit_idx`` is needed.
 
-A float16 DB is best passed as its int16 bit pattern, which is how
-``DeviceDB`` keeps it in HBM: Mosaic loads no float16, and the
-bitcast of a float16 operand here costs a copy of the whole DB on TPU.
+A float16 DB enters only as its int16 bit pattern (``codec.hbm_form``),
+which is how the device arenas keep it in HBM: Mosaic loads no float16,
+and converting a float16 operand here would copy the whole DB on TPU.
 """
 from __future__ import annotations
 
@@ -39,7 +39,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.memo_attention.kernel import (NEG_INF, as_f16,
+from repro.kernels.memo_attention.kernel import (NEG_INF, db_to_f32,
                                                  memo_attention_bhsd)
 
 
@@ -80,8 +80,6 @@ def _memo_attention_pallas(q, k, v, db_apm, db_scales, hit_idx, hit, lengths,
     q = _pad_axis(q, 1, Sp - S)
     k = _pad_axis(k, 1, Sp - S)
     v = _pad_axis(v, 1, Sp - S)
-    if db_apm.dtype == jnp.float16:
-        db_apm = jax.lax.bitcast_convert_type(db_apm, jnp.int16)
     db_apm = _fit_db(db_apm, Sp, 2)       # pads only past the DB's length
     if has_scales:
         db_scales = _fit_db(db_scales, Sp, 1)
@@ -126,10 +124,9 @@ def _memo_attention_xla(q, k, v, db_apm, db_scales, hit_idx, hit, lengths, *,
     m = jnp.max(s, -1, keepdims=True)
     p = jnp.where(s <= NEG_INF * 0.5, 0.0, jnp.exp(s - m))
     p = p / jnp.maximum(jnp.sum(p, -1, keepdims=True), 1e-30)
-    apm = as_f16(jnp.take(db_apm, hit_idx, axis=0)).astype(jnp.float32)
+    apm = db_to_f32(jnp.take(db_apm, hit_idx, axis=0))
     if has_scales:
-        apm = apm * as_f16(jnp.take(db_scales, hit_idx,
-                                    axis=0)).astype(jnp.float32)[..., None]
+        apm = apm * db_to_f32(jnp.take(db_scales, hit_idx, axis=0))[..., None]
     apm = _fit_db(apm, S, 2)
     p = jnp.where((hit == 1)[:, None, None, None],
                   apm, p.reshape(B, H, S, S))
@@ -141,9 +138,10 @@ def memo_attention(q, k, v, db_apm, hit_idx, hit, *, db_scales=None,
                    lengths=None, causal=True, window=None, block_q=128,
                    block_k=128, interpret=None, impl=None):
     """Model layout: q (B,S,H,dh), k/v (B,S,Hkv,dh), db_apm (N,H,L,L)
-    (int16 = float16 bit patterns), hit_idx/hit (B,). With ``db_scales``
-    (N,H,L) the DB is int8-quantized (the ``int8`` APM codec) and tiles
-    dequantize in VMEM — the fused-dequant gather (DESIGN.md §2.6). ``lengths`` (B,) serves
+    (int16 = float16 bit patterns; a float16 array is refused), hit_idx/hit
+    (B,). With ``db_scales`` (N,H,L) the DB is int8-quantized (the
+    ``int8`` APM codec) and tiles dequantize in VMEM — the fused-dequant
+    gather (DESIGN.md §2.6). ``lengths`` (B,) serves
     variable-length batches: padded key positions are masked out of the
     miss path per sequence (hit APMs are already zero past their length).
 
@@ -151,6 +149,10 @@ def memo_attention(q, k, v, db_apm, hit_idx, hit, *, db_scales=None,
     docstring); None auto-resolves by backend, except that an explicit
     ``interpret`` pins the Pallas path. ``interpret=None`` resolves per
     backend: Pallas interpreter on CPU, compiled on TPU."""
+    if db_apm.dtype == jnp.float16:
+        raise TypeError("memo_attention takes a float16 DB as its int16 bit "
+                        "pattern (repro.core.codec.hbm_form), as the device "
+                        "arenas hold it")
     if impl is None:
         impl = ("pallas" if interpret is not None
                 else ("xla" if jax.default_backend() == "cpu" else "pallas"))

@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.core.codec import hbm_form
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.memo_attention.ops import memo_attention
@@ -198,20 +199,26 @@ def test_memo_int8_scale_boundaries():
     apm[0, :, 0, 0] = 1.0          # a full-magnitude element → code 127
     apm[1, :, 1, :] = 0.0          # all-zero row → scale floor path
     codes, scales = _quantize_rows(apm)
-    hit_idx = jnp.array([0, 1, 3])
+    # hits gather entries other than their own batch row, so a scale
+    # operand indexed by the wrong one shows
+    hit_idx = jnp.array([1, 0, 3])
     hit = jnp.array([1, 1, 0])
     ref = memo_attention_q8_ref(
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
         v.transpose(0, 2, 1, 3), jnp.asarray(codes), jnp.asarray(scales),
         hit_idx, hit, causal=True).transpose(0, 2, 1, 3)
-    for impl in ("pallas", "xla"):
-        out = memo_attention(q, k, v, jnp.asarray(codes), hit_idx, hit,
-                             db_scales=jnp.asarray(scales), causal=True,
-                             block_q=16, block_k=16,
-                             interpret=True if impl == "pallas" else None,
-                             impl=impl)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5, err_msg=impl)
+    # the scales as float16 and in their HBM form (int16 bit patterns,
+    # gathered and decoded before the dispatch)
+    for form, sc in (("f16", scales), ("hbm", hbm_form(scales))):
+        for impl in ("pallas", "xla"):
+            out = memo_attention(q, k, v, jnp.asarray(codes), hit_idx, hit,
+                                 db_scales=jnp.asarray(sc), causal=True,
+                                 block_q=16, block_k=16,
+                                 interpret=True if impl == "pallas" else None,
+                                 impl=impl)
+            np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                       rtol=2e-5, atol=2e-5,
+                                       err_msg=f"{impl} {form} scales")
 
 
 def test_memo_ragged_seq_padding():
@@ -238,13 +245,14 @@ def test_memo_ragged_seq_padding():
 
 def test_memo_xla_impl_matches_pallas():
     """The one-matmul XLA form and the tiled kernel are one contract:
-    identical outputs on a mixed batch (f16 DB and int8 DB)."""
+    identical outputs on a mixed batch (f32 DB, an f16 DB in its HBM
+    form, and int8 DB with float16 or int16-bit scales)."""
     from repro.core.codec import _quantize_rows
     B, S, H, Hkv, dh, N = 4, 48, 4, 2, 16, 5
     q, k, v = _qkv(jax.random.PRNGKey(18), B, S, H, Hkv, dh, jnp.float32)
     apm = np.asarray(jax.nn.softmax(
         jax.random.normal(jax.random.PRNGKey(19), (N, H, S, S)), -1))
-    hit_idx = jnp.array([0, 4, 2, 1])
+    hit_idx = jnp.array([3, 4, 0, 1])     # hits gather other rows than b
     hit = jnp.array([1, 0, 1, 0])
     a = memo_attention(q, k, v, jnp.asarray(apm), hit_idx, hit, causal=True,
                        block_q=16, block_k=16, interpret=True, impl="pallas")
@@ -252,15 +260,43 @@ def test_memo_xla_impl_matches_pallas():
                        impl="xla")
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=2e-5, atol=2e-5)
+    # f16 arena as the device tier holds it (int16 bits): the kernel's
+    # in-VMEM decode and the XLA form both replay the f16 values exactly
+    apm16 = apm.astype(np.float16)
+    ref = memo_attention_ref(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                             v.transpose(0, 2, 1, 3),
+                             jnp.asarray(apm16, jnp.float32), hit_idx, hit,
+                             causal=True).transpose(0, 2, 1, 3)
+    for impl in ("pallas", "xla"):
+        out = memo_attention(q, k, v, jnp.asarray(hbm_form(apm16)), hit_idx,
+                             hit, causal=True, block_q=16, block_k=16,
+                             interpret=True if impl == "pallas" else None,
+                             impl=impl)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5, err_msg=impl)
     codes, scales = _quantize_rows(apm)
-    aq = memo_attention(q, k, v, jnp.asarray(codes), hit_idx, hit,
-                        db_scales=jnp.asarray(scales), causal=True,
-                        block_q=16, block_k=16, interpret=True, impl="pallas")
-    bq = memo_attention(q, k, v, jnp.asarray(codes), hit_idx, hit,
-                        db_scales=jnp.asarray(scales), causal=True,
-                        impl="xla")
-    np.testing.assert_allclose(np.asarray(aq), np.asarray(bq),
-                               rtol=2e-5, atol=2e-5)
+    for sc in (scales, hbm_form(scales)):
+        aq = memo_attention(q, k, v, jnp.asarray(codes), hit_idx, hit,
+                            db_scales=jnp.asarray(sc), causal=True,
+                            block_q=16, block_k=16, interpret=True,
+                            impl="pallas")
+        bq = memo_attention(q, k, v, jnp.asarray(codes), hit_idx, hit,
+                            db_scales=jnp.asarray(sc), causal=True,
+                            impl="xla")
+        np.testing.assert_allclose(np.asarray(aq), np.asarray(bq),
+                                   rtol=2e-5, atol=2e-5, err_msg=str(sc.dtype))
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+def test_memo_refuses_float16_db(impl):
+    """A float16 DB has one way in, its int16 bit pattern: converting it
+    inside the wrapper would copy the whole arena on every call."""
+    q, k, v = _qkv(jax.random.PRNGKey(21), 1, 16, 2, 2, 16, jnp.float32)
+    db = jnp.zeros((2, 2, 16, 16), jnp.float16)
+    zeros = jnp.zeros((1,), jnp.int32)
+    with pytest.raises(TypeError, match="hbm_form"):
+        memo_attention(q, k, v, db, zeros, zeros, impl=impl,
+                       interpret=True if impl == "pallas" else None)
 
 
 def test_memo_varlen_lengths():

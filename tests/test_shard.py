@@ -63,6 +63,9 @@ def test_top1_parity_and_fetched_payload():
     slots = s.admit(apms, embs)
     s.sync(force_full=True)
     di = s.device_index
+    # the f16 arena and the hot set in their HBM form, as in DeviceDB
+    assert s.device_db.parts[0].dtype == jnp.int16
+    assert di._hot_parts[0].dtype == jnp.int16
     d2, got, rows = di.search_fetch(jnp.asarray(embs), args=di.search_args,
                                     parts=s.device_db.parts)
     np.testing.assert_array_equal(np.asarray(got)[:, 0], slots)

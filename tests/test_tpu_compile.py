@@ -82,11 +82,13 @@ def test_memo_attention_compiles_for_v5e(one_chip, codec, causal, S):
 
 def test_memo_attention_short_bucket_does_not_copy_the_arena(one_chip):
     """A bucket shorter than the arena pads q/k/v up to the arena length
-    instead of slicing the (N, H, L, L) DB: no temporary of DB size."""
+    instead of slicing the (N, H, L, L) DB. The bound, in bytes, is the
+    smallest whole-arena copy: the int16 arena sliced to the bucket."""
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    qkv = sds((B, 64, H, DH), jnp.float32)
+    S = 64
+    qkv = sds((B, S, H, DH), jnp.float32)
     idx = sds((B,), jnp.int32)
 
     def fn(q, k, v, db, hit_idx, hit):
@@ -95,7 +97,8 @@ def test_memo_attention_short_bucket_does_not_copy_the_arena(one_chip):
     compiled = jax.jit(fn).lower(qkv, qkv, qkv,
                                  sds((N, H, L, L), jnp.int16),
                                  idx, idx).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < N * H * L * L
+    sliced_arena = N * H * S * S * jnp.dtype(jnp.int16).itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < sliced_arena
 
 
 @pytest.mark.parametrize("queries", [8, 1000])
