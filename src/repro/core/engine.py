@@ -35,6 +35,7 @@ from repro.core.index import DeviceIndex
 from repro.core.prefill import PrefillCodec, stack_kv, unstack_kv_rows
 from repro.core.selective import LayerProfile, PerfModel, timeit_median
 from repro.core.similarity import similarity_score
+from repro.core.spans import span
 from repro.core.store import MemoStore, StoreSnapshot
 # MemoConfig/MemoSpec live in repro.memo.specs (the public API v1 config
 # surface); re-exported here so ``from repro.core.engine import
@@ -115,7 +116,6 @@ class MemoStats:
     t_search: float = 0.0
     t_fetch: float = 0.0
     t_attn: float = 0.0
-    t_other: float = 0.0
     t_total: float = 0.0            # whole-batch wall time (fast path)
     per_layer_hits: Dict[int, int] = field(default_factory=dict)
     n_admitted: int = 0             # entries admitted via miss capture
@@ -139,7 +139,6 @@ class MemoStats:
             self.t_search += other.t_search
             self.t_fetch += other.t_fetch
             self.t_attn += other.t_attn
-            self.t_other += other.t_other
             self.t_total += other.t_total
             self.n_admitted += other.n_admitted
             for li, nh in other.per_layer_hits.items():
@@ -576,69 +575,71 @@ class MemoEngine:
                 "prepare_batch drives the device fast path; build() the "
                 "engine in bucket/kernel mode (select and host paths go "
                 "through infer())")
-        cfg = self.cfg
-        tokens = jnp.asarray(batch["tokens"])
-        lengths = batch.get("lengths")
-        thr = self.mc.threshold if threshold is None else float(threshold)
-        active = set(self.layers if active_layers is None
-                     else active_layers)
-        capture = self._capture_now(True, prefill=prefill)
-        self._serve_batches += 1
-        if sync_store:
-            self.store.sync()     # generation-counted: no-op unless stale
-        view = self.store.snapshot
-        if view is None:          # bootstrap: materialize + publish once
-            self.store.sync()
+        with span("prepare"):
+            cfg = self.cfg
+            tokens = jnp.asarray(batch["tokens"])
+            lengths = batch.get("lengths")
+            thr = self.mc.threshold if threshold is None else float(threshold)
+            active = set(self.layers if active_layers is None
+                         else active_layers)
+            capture = self._capture_now(True, prefill=prefill)
+            self._serve_batches += 1
+            if sync_store:
+                self.store.sync()     # generation-counted: no-op unless stale
             view = self.store.snapshot
-        B, S = tokens.shape[0], tokens.shape[1]
-        n_valid = int(batch.get("n_valid", B))
-        cache_len, cache_tpls = 0, None
-        if prefill:
-            if not self.mc.prefill.enabled:
-                raise RuntimeError(
-                    "prefill serving needs PrefillSpec(enabled=True) at "
-                    "build time — the store must carry KV-bearing entries")
-            if not isinstance(self.store.codec, PrefillCodec):
-                raise RuntimeError(
-                    "this store's entries carry no KV parts; rebuild (or "
-                    "re-save) it with prefill_enabled=True")
-            self._check_prefill_supported()
-            cache_len = self._prefill_cache_len(S)
-            cache_tpls = self._split_caches(
-                self.model.init_caches(B, cache_len))
-            for li in sorted(set(self.layers) & active):
-                cl = bb.cache_len_from(cache_tpls[li])
-                if cl < S:
-                    raise ValueError(
-                        f"layer {li} decode cache holds {cl} slots < "
-                        f"prompt length {S} (sliding windows shorter "
-                        f"than the prompt cannot replay a stored "
-                        f"prefix)")
-        t0 = time.perf_counter()
-        key = ("prolog", lengths is not None)
-        prolog = self._jit_cache.get(key)
-        if prolog is None:
-            def prolog(params, tokens, ln):
-                h = bb.embed_tokens(params, tokens, cfg)
-                S = tokens.shape[1]
-                positions = jnp.broadcast_to(
-                    jnp.arange(S, dtype=jnp.int32), tokens.shape[:2])
-                kpad = (None if ln is None else
-                        jnp.arange(S, dtype=jnp.int32)[None, :]
-                        < ln[:, None])
-                return h, positions, kpad
-            prolog = self._jit_cache[key] = jax.jit(prolog)
-        len_dev = (None if lengths is None
-                   else jnp.asarray(lengths, jnp.int32))
-        if lengths is not None and not isinstance(lengths, np.ndarray):
-            lengths = np.asarray(lengths)
-        h, positions, kpad = prolog(self.params, tokens, len_dev)
-        return PreparedBatch(
-            tokens=tokens, h=h, positions=positions, kpad=kpad,
-            lengths_dev=len_dev, lengths=lengths,
-            n_valid=n_valid, thr=thr, active=active, capture=capture,
-            view=view, t0=t0, prefill=prefill, cache_len=cache_len,
-            cache_tpls=cache_tpls)
+            if view is None:          # bootstrap: materialize + publish once
+                self.store.sync()
+                view = self.store.snapshot
+            B, S = tokens.shape[0], tokens.shape[1]
+            n_valid = int(batch.get("n_valid", B))
+            cache_len, cache_tpls = 0, None
+            if prefill:
+                if not self.mc.prefill.enabled:
+                    raise RuntimeError(
+                        "prefill serving needs PrefillSpec(enabled=True) "
+                        "at build time — the store must carry KV-bearing "
+                        "entries")
+                if not isinstance(self.store.codec, PrefillCodec):
+                    raise RuntimeError(
+                        "this store's entries carry no KV parts; rebuild (or "
+                        "re-save) it with prefill_enabled=True")
+                self._check_prefill_supported()
+                cache_len = self._prefill_cache_len(S)
+                cache_tpls = self._split_caches(
+                    self.model.init_caches(B, cache_len))
+                for li in sorted(set(self.layers) & active):
+                    cl = bb.cache_len_from(cache_tpls[li])
+                    if cl < S:
+                        raise ValueError(
+                            f"layer {li} decode cache holds {cl} slots < "
+                            f"prompt length {S} (sliding windows shorter "
+                            f"than the prompt cannot replay a stored "
+                            f"prefix)")
+            t0 = time.perf_counter()
+            key = ("prolog", lengths is not None)
+            prolog = self._jit_cache.get(key)
+            if prolog is None:
+                def memo_prolog(params, tokens, ln):
+                    h = bb.embed_tokens(params, tokens, cfg)
+                    S = tokens.shape[1]
+                    positions = jnp.broadcast_to(
+                        jnp.arange(S, dtype=jnp.int32), tokens.shape[:2])
+                    kpad = (None if ln is None else
+                            jnp.arange(S, dtype=jnp.int32)[None, :]
+                            < ln[:, None])
+                    return h, positions, kpad
+                prolog = self._jit_cache[key] = jax.jit(memo_prolog)
+            len_dev = (None if lengths is None
+                       else jnp.asarray(lengths, jnp.int32))
+            if lengths is not None and not isinstance(lengths, np.ndarray):
+                lengths = np.asarray(lengths)
+            h, positions, kpad = prolog(self.params, tokens, len_dev)
+            return PreparedBatch(
+                tokens=tokens, h=h, positions=positions, kpad=kpad,
+                lengths_dev=len_dev, lengths=lengths,
+                n_valid=n_valid, thr=thr, active=active, capture=capture,
+                view=view, t0=t0, prefill=prefill, cache_len=cache_len,
+                cache_tpls=cache_tpls)
 
     def run_layers(self, prep: PreparedBatch) -> PreparedBatch:
         """The device-resident serving loop (DESIGN.md §2): every layer is
@@ -651,39 +652,44 @@ class MemoEngine:
         are STAGED ON DEVICE the same way — the loop never blocks."""
         thr_dev = jnp.float32(prep.thr)
         h = prep.h
-        if prep.prefill:
-            # memoized causal prefill: memoized layers hand back the
-            # layer's decode cache alongside h (hits from the stored KV
-            # entry, misses from the freshly computed K/V); every other
-            # layer runs the backbone's exact prefill step
+        with span("run_layers"):
             for li, kind, lp in self._iter_layers():
-                if li in prep.active and kind == "attn":
-                    h, ck, cv, *rest = self._layer_fused_prefill(
-                        lp, h, li, thr_dev, prep.positions,
-                        view=prep.view, cache_tpl=prep.cache_tpls[li],
-                        kpad=prep.kpad, qlen=prep.lengths_dev,
-                        capture=prep.capture)
-                    prep.caches_by_li[li] = {"k": ck, "v": cv}
-                    prep.pend.append((li, *rest))
-                else:
-                    h, c = self._layer_plain_prefill(
-                        lp, h, kind, li, prep.positions,
-                        prep.cache_tpls[li], kpad=prep.kpad)
-                    prep.caches_by_li[li] = c
-            prep.h = h
-            return prep
-        for li, kind, lp in self._iter_layers():
-            if li in prep.active and kind in ("attn", "mla"):
-                h, *rest = self._layer_fused(
-                    lp, h, kind, li, thr_dev, prep.positions,
-                    view=prep.view, kpad=prep.kpad,
-                    qlen=prep.lengths_dev, capture=prep.capture)
-                prep.pend.append((li, *rest))
-            else:
-                h = self._layer_plain(lp, h, kind, li, None, prep.positions,
-                                      kpad=prep.kpad)
+                with span("layer", layer=li):
+                    h = self._dispatch_layer(prep, li, kind, lp, h, thr_dev)
         prep.h = h
         return prep
+
+    def _dispatch_layer(self, prep: PreparedBatch, li, kind, lp, h,
+                        thr_dev):
+        """Issue one layer of ``run_layers`` and return its hidden-state
+        output, device arrays in and out. A memoized causal prefill's
+        memoized layers hand back the layer's decode cache alongside h
+        (hits from the stored KV entry, misses from the freshly computed
+        K/V); its other layers run the backbone's exact prefill step."""
+        if prep.prefill:
+            if li in prep.active and kind == "attn":
+                h, ck, cv, *rest = self._layer_fused_prefill(
+                    lp, h, li, thr_dev, prep.positions,
+                    view=prep.view, cache_tpl=prep.cache_tpls[li],
+                    kpad=prep.kpad, qlen=prep.lengths_dev,
+                    capture=prep.capture)
+                prep.caches_by_li[li] = {"k": ck, "v": cv}
+                prep.pend.append((li, *rest))
+            else:
+                h, c = self._layer_plain_prefill(
+                    lp, h, kind, li, prep.positions,
+                    prep.cache_tpls[li], kpad=prep.kpad)
+                prep.caches_by_li[li] = c
+            return h
+        if li in prep.active and kind in ("attn", "mla"):
+            h, *rest = self._layer_fused(
+                lp, h, kind, li, thr_dev, prep.positions,
+                view=prep.view, kpad=prep.kpad,
+                qlen=prep.lengths_dev, capture=prep.capture)
+            prep.pend.append((li, *rest))
+            return h
+        return self._layer_plain(lp, h, kind, li, None, prep.positions,
+                                 kpad=prep.kpad)
 
     def finalize(self, prep: PreparedBatch,
                  stats: Optional[MemoStats] = None):
@@ -698,30 +704,32 @@ class MemoEngine:
             # logits), so exact-vs-memoized parity compares like for like
             headpf = self._jit_cache.get("headpf")
             if headpf is None:
-                def headpf(params, h):
+                def memo_head_prefill(params, h):
                     return bb.logits_from_hidden(
                         params, h[:, -1:], cfg)[:, 0]
-                headpf = self._jit_cache["headpf"] = jax.jit(headpf)
-            logits = jax.block_until_ready(
-                headpf(self.params, prep.h))                # ONE barrier
+                headpf = self._jit_cache["headpf"] = jax.jit(
+                    memo_head_prefill)
+            logits = headpf(self.params, prep.h)
+            with span("barrier"):
+                logits = jax.block_until_ready(logits)      # ONE barrier
             out = (logits, self._merge_caches(prep.caches_by_li))
         else:
             key = ("head", prep.kpad is not None)
             head = self._jit_cache.get(key)
             if head is None:
-                def head(params, h, kpad):
+                def memo_head(params, h, kpad):
                     return (bb.classify_from_hidden(params, h, cfg,
                                                     kpad=kpad)
                             if cfg.n_classes
                             else bb.logits_from_hidden(params, h, cfg))
-                head = self._jit_cache[key] = jax.jit(head)
-            out = jax.block_until_ready(
-                head(self.params, prep.h, prep.kpad))       # ONE barrier
-        dt = time.perf_counter() - prep.t0
+                head = self._jit_cache[key] = jax.jit(memo_head)
+            out = head(self.params, prep.h, prep.kpad)
+            with span("barrier"):
+                out = jax.block_until_ready(out)            # ONE barrier
         st.n_inputs += prep.n_valid
-        st.t_total += dt
-        st.t_attn += dt
-        payload = self._drain_stats(prep, st)
+        st.t_total += time.perf_counter() - prep.t0
+        with span("drain"):
+            payload = self._drain_stats(prep, st)
         return out, st, payload
 
     def _layer_fused(self, lp, h, kind, li, thr_dev, positions, view,
@@ -839,8 +847,8 @@ class MemoEngine:
 
             arena_len = self.store.apm_shape[-1]
 
-            def run(lp, emb_p, sargs, db_parts, ent_lens, h, thr, a, b,
-                    positions, qlen, kpad):
+            def memo_layer(lp, emb_p, sargs, db_parts, ent_lens, h, thr,
+                           a, b, positions, qlen, kpad):
                 x = bb.norm_apply(lp["norm1"], h, cfg.norm)
                 emb = embed_apply(emb_p, x, pool, act, lengths=qlen,
                                   full_len=arena_len)
@@ -968,7 +976,7 @@ class MemoEngine:
                                         kpad=kpad, return_apm=True)
                     out = out + (emb, apm_cap.astype(jnp.float16))
                 return out
-            fn = jax.jit(run)
+            fn = jax.jit(memo_layer)
             self._jit_cache[key] = fn
         return fn(lp, self.embedder.params, view.search_args,
                   view.db_parts, view.lengths, h, thr_dev,
@@ -1058,8 +1066,8 @@ class MemoEngine:
                                              ops),
                     (xs, apm, mk, mv, hit, pos, kp))
 
-            def run(lp, emb_p, sargs, db_parts, ent_lens, h, thr, a, b,
-                    positions, qlen, kpad):
+            def memo_layer_prefill(lp, emb_p, sargs, db_parts, ent_lens,
+                                   h, thr, a, b, positions, qlen, kpad):
                 x = bb.norm_apply(lp["norm1"], h, cfg.norm)
                 emb = embed_apply(emb_p, x, pool, act, lengths=qlen,
                                   full_len=arena_len)
@@ -1133,7 +1141,7 @@ class MemoEngine:
                         1).astype(jnp.float16)
                     out = out + (emb, apm_cap.astype(jnp.float16), kv_cap)
                 return out
-            fn = jax.jit(run)
+            fn = jax.jit(memo_layer_prefill)
             self._jit_cache[key] = fn
         return fn(lp, self.embedder.params, view.search_args,
                   view.db_parts, view.lengths, h, thr_dev,
@@ -1151,13 +1159,13 @@ class MemoEngine:
         if fn is None:
             cfg = self.cfg
 
-            def run(lp, h, positions, cache, kpad):
+            def memo_layer_plain_prefill(lp, h, positions, cache, kpad):
                 out, c, _, _ = bb._layer_apply(
                     lp, h, cfg, kind, li, mode="prefill",
                     positions=positions, pos=None, cache=cache,
                     kpad=kpad)
                 return out, c
-            fn = jax.jit(run)
+            fn = jax.jit(memo_layer_plain_prefill)
             self._jit_cache[key] = fn
         return fn(lp, h, positions, cache, kpad)
 
@@ -1192,10 +1200,10 @@ class MemoEngine:
         if fn is None:
             model = self.model
 
-            def run(params, tokens):
+            def memo_prefill_exact(params, tokens):
                 return model.prefill(params, {"tokens": tokens},
                                      cache_len=Sc)
-            fn = self._jit_cache[key] = jax.jit(run)
+            fn = self._jit_cache[key] = jax.jit(memo_prefill_exact)
         return fn(self.params, tokens)
 
     def _capture_now(self, use_memo: bool, prefill: bool = False) -> bool:
@@ -1608,12 +1616,12 @@ class MemoEngine:
         if fn is None:
             cfg = self.cfg
 
-            def run(lp, h, memo, positions, kpad):
+            def memo_layer_plain(lp, h, memo, positions, kpad):
                 out, _, _, _ = bb._layer_apply(
                     lp, h, cfg, kind, li, mode="full", positions=positions,
                     pos=None, cache=None, memo=memo, kpad=kpad)
                 return out
-            fn = jax.jit(run)
+            fn = jax.jit(memo_layer_plain)
             self._jit_cache[key] = fn
         return fn(lp, h, memo, positions, kpad)
 

@@ -60,6 +60,7 @@ import numpy as np
 
 from repro.core.engine import MemoEngine, MemoStats
 from repro.core.faults import fire
+from repro.core.spans import span
 
 
 class Health(enum.Enum):
@@ -286,24 +287,27 @@ class MemoServer:
             return []
         q = self._queues[key]
         reqs = [q.popleft() for _ in range(min(len(q), self.max_batch))]
-        return self._execute(key[0], reqs, prefill=key[1])
+        rows = self._pad_rows(len(reqs))
+        with span("step", batch=self.n_batches, bucket=key[0], rows=rows,
+                  n_valid=len(reqs), queued=self.queued):
+            return self._execute(key[0], reqs, rows, prefill=key[1])
 
-    def _execute(self, bucket: int, reqs: List[Request],
+    def _execute(self, bucket: int, reqs: List[Request], rows: int,
                  prefill: bool = False) -> List[Completion]:
         eng = self.engine
         n = len(reqs)
-        rows = self._pad_rows(n)
-        toks = np.zeros((rows, bucket), np.int32)
-        lens = np.empty((rows,), np.int32)
-        for i, r in enumerate(reqs):
-            toks[i, : r.tokens.size] = r.tokens
-            lens[i] = r.tokens.size
-        if rows > n:                    # filler rows replay row 0
-            toks[n:] = toks[0]
-            lens[n:] = lens[0]
-            self.n_filler_rows += rows - n
-        batch = {"tokens": jnp.asarray(toks), "lengths": lens,
-                 "n_valid": n}
+        with span("assemble"):
+            toks = np.zeros((rows, bucket), np.int32)
+            lens = np.empty((rows,), np.int32)
+            for i, r in enumerate(reqs):
+                toks[i, : r.tokens.size] = r.tokens
+                lens[i] = r.tokens.size
+            if rows > n:                    # filler rows replay row 0
+                toks[n:] = toks[0]
+                lens[n:] = lens[0]
+                self.n_filler_rows += rows - n
+            batch = {"tokens": jnp.asarray(toks), "lengths": lens,
+                     "n_valid": n}
         st = MemoStats()
         if self.async_maintenance:
             self._check_worker()
@@ -312,10 +316,11 @@ class MemoServer:
             # the engine's no-memo path — logits bit-identical to
             # ``infer(use_memo=False)`` / ``prefill_exact``, no store
             # reads, no maintenance
-            if prefill:
-                out = eng.prefill_exact(batch)
-            else:
-                out, st = eng.infer(batch, stats=st, use_memo=False)
+            with span("exact"):
+                if prefill:
+                    out = eng.prefill_exact(batch)
+                else:
+                    out, st = eng.infer(batch, stats=st, use_memo=False)
             self.n_exact_batches += 1
         else:
             prep = eng.prepare_batch(batch, prefill=prefill,
@@ -326,40 +331,43 @@ class MemoServer:
                 if self._worker is None:   # closed: nobody drains the
                     raise RuntimeError(    # queue — fail loudly instead
                         "MemoServer is closed")  # of blocking on put()
-                self._enqueue_payload(payload)
+                with span("handoff", depth=self._maint_q.qsize()):
+                    self._enqueue_payload(payload)
             else:
-                eng.apply_maintenance(payload, stats=self.stats)
-                self._after_apply()
+                with span("maintain"):
+                    eng.apply_maintenance(payload, stats=self.stats)
+                    self._after_apply()
         self.stats.merge(st)
         self.n_batches += 1
         done = self._now()
-        comps = []
-        if prefill:
-            logits_all, caches = out
-            out_np = np.asarray(logits_all)          # (rows, vocab)
-            by_li = eng._split_caches(caches)
+        with span("complete"):
+            comps = []
+            if prefill:
+                logits_all, caches = out
+                out_np = np.asarray(logits_all)          # (rows, vocab)
+                by_li = eng._split_caches(caches)
+                for i, r in enumerate(reqs):
+                    # per-request decode caches: slice batch row i out of
+                    # every cache leaf, then re-merge into the segment
+                    # pytree model.decode_step consumes (slicing the merged
+                    # tree directly would hit scan segments' leading reps
+                    # axis instead of the batch axis)
+                    c_i = eng._merge_caches({
+                        li: jax.tree.map(lambda a, i=i: a[i: i + 1], c)
+                        for li, c in by_li.items()})
+                    comps.append(Completion(
+                        rid=r.rid, logits=out_np[i], latency=done - r.arrival,
+                        length=int(r.tokens.size), bucket=bucket,
+                        batch_rows=n, caches=c_i))
+                return comps
+            out_np = np.asarray(out)
             for i, r in enumerate(reqs):
-                # per-request decode caches: slice batch row i out of
-                # every cache leaf, then re-merge into the segment
-                # pytree model.decode_step consumes (slicing the merged
-                # tree directly would hit scan segments' leading reps
-                # axis instead of the batch axis)
-                c_i = eng._merge_caches({
-                    li: jax.tree.map(lambda a, i=i: a[i: i + 1], c)
-                    for li, c in by_li.items()})
+                logits = (out_np[i] if out_np.ndim == 2
+                          else out_np[i, : r.tokens.size])
                 comps.append(Completion(
-                    rid=r.rid, logits=out_np[i], latency=done - r.arrival,
-                    length=int(r.tokens.size), bucket=bucket,
-                    batch_rows=n, caches=c_i))
+                    rid=r.rid, logits=logits, latency=done - r.arrival,
+                    length=int(r.tokens.size), bucket=bucket, batch_rows=n))
             return comps
-        out_np = np.asarray(out)
-        for i, r in enumerate(reqs):
-            logits = (out_np[i] if out_np.ndim == 2
-                      else out_np[i, : r.tokens.size])
-            comps.append(Completion(
-                rid=r.rid, logits=logits, latency=done - r.arrival,
-                length=int(r.tokens.size), bucket=bucket, batch_rows=n))
-        return comps
 
     # ----------------------------------------------------------- health
     def _set_health(self, health: Health, reason: str) -> None:
@@ -448,7 +456,8 @@ class MemoServer:
             try:
                 if item is None:
                     return
-                self._apply_supervised(item)
+                with span("maintain"):
+                    self._apply_supervised(item)
             finally:
                 self._maint_busy_since = None
                 self._maint_q.task_done()
