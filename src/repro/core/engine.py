@@ -693,10 +693,11 @@ class MemoEngine:
 
     def finalize(self, prep: PreparedBatch,
                  stats: Optional[MemoStats] = None):
-        """Head jit + the ONE trailing barrier, then the event-based stats
-        drain. Returns ``(outputs, stats, payload)`` — the payload carries
-        every piece of host-tier store work from this batch; the caller
-        decides WHERE it runs (inline vs the maintenance worker)."""
+        """Head jit and the ``memo_drain`` stats program, then the ONE
+        trailing barrier, then the event-based stats drain. Returns
+        ``(outputs, stats, payload)`` — the payload carries every piece of
+        host-tier store work from this batch; the caller decides WHERE it
+        runs (inline vs the maintenance worker)."""
         st = stats or MemoStats()
         cfg = self.cfg
         if prep.prefill:
@@ -710,6 +711,7 @@ class MemoEngine:
                 headpf = self._jit_cache["headpf"] = jax.jit(
                     memo_head_prefill)
             logits = headpf(self.params, prep.h)
+            drained = self._memo_drain(prep)
             with span("barrier"):
                 logits = jax.block_until_ready(logits)      # ONE barrier
             out = (logits, self._merge_caches(prep.caches_by_li))
@@ -724,12 +726,13 @@ class MemoEngine:
                             else bb.logits_from_hidden(params, h, cfg))
                 head = self._jit_cache[key] = jax.jit(memo_head)
             out = head(self.params, prep.h, prep.kpad)
+            drained = self._memo_drain(prep)
             with span("barrier"):
                 out = jax.block_until_ready(out)            # ONE barrier
         st.n_inputs += prep.n_valid
         st.t_total += time.perf_counter() - prep.t0
-        with span("drain"):
-            payload = self._drain_stats(prep, st)
+        with span("drain", layers=len(prep.pend)):
+            payload = self._drain_stats(prep, st, drained)
         return out, st, payload
 
     def _layer_fused(self, lp, h, kind, li, thr_dev, positions, view,
@@ -1310,26 +1313,53 @@ class MemoEngine:
                     lambda *a: jnp.stack(a), *groups)
         return caches
 
-    def _drain_stats(self, prep: PreparedBatch,
-                     st: MemoStats) -> MaintenancePayload:
-        """Materialize the per-layer device counters in O(1) stacked host
-        transfers per batch (TWO: sims+hits as one f32 block, slots as one
-        i32 block — plus embs and APMs under capture), after the trailing
-        barrier. Rows past ``n_valid`` (runtime batch padding) are
-        dropped. Returns the MaintenancePayload — reuse slots and captured
-        misses — WITHOUT touching the store: the caller decides where
-        maintenance runs (inline vs the MemoServer worker)."""
+    def _memo_drain(self, prep: PreparedBatch):
+        """Dispatch ``memo_drain``: ONE jitted program that packs the
+        per-layer device stats of ``prep.pend`` into one int32 block
+        (L, 3, B) — sims bit-cast from float32, hits, slots — plus, under
+        capture, the stacked embs, APMs and (prefill) KV planes. Issued
+        before the barrier so its dispatch overlaps the device; returns
+        the device outputs, or None when no layer was memoized. All B
+        rows are packed: ``_drain_stats`` slices ``n_valid`` on the host,
+        so a batch's fill never retraces the program."""
+        pend = prep.pend
+        if not pend:
+            return None
+        n_cap = len(pend[0]) - 4 if prep.capture else 0
+        key = ("drain", prep.h.shape, len(pend), n_cap)
+        fn = self._jit_cache.get(key)
+        if fn is None:
+            def memo_drain(per_layer):
+                stats = jnp.stack([jnp.stack([
+                    jax.lax.bitcast_convert_type(
+                        sims.astype(jnp.float32), jnp.int32),
+                    hits.astype(jnp.int32), slots.astype(jnp.int32)])
+                    for sims, hits, slots, *_ in per_layer])
+                return (stats,) + tuple(
+                    jnp.stack([p[3 + k] for p in per_layer])
+                    for k in range(n_cap))
+            fn = self._jit_cache[key] = jax.jit(memo_drain)
+        return fn(tuple(p[1:4 + n_cap] for p in pend))
+
+    def _drain_stats(self, prep: PreparedBatch, st: MemoStats,
+                     drained) -> MaintenancePayload:
+        """Read ``memo_drain``'s outputs (``drained``, dispatched before
+        the barrier) to the host in ONE ``jax.device_get`` and fold them
+        into ``st``. Rows past ``n_valid`` (runtime batch padding) are
+        dropped here, on the host. Returns the MaintenancePayload — reuse
+        slots and captured misses — WITHOUT touching the store: the
+        caller decides where maintenance runs (inline vs the MemoServer
+        worker)."""
         pend = prep.pend
         out = MaintenancePayload(
             generation=getattr(prep.view, "generation", -1))
-        if not pend:
+        if drained is None:
             return out
         nv = prep.n_valid
-        payload = np.asarray(jnp.stack(
-            [jnp.stack([p[1], p[2].astype(jnp.float32)]) for p in pend]))
-        slots = np.asarray(jnp.stack([p[3] for p in pend]))[:, :nv]
-        hits = payload[:, 1, :nv] > 0.5                          # (L, nv)
-        sims = payload[:, 0, :nv]
+        stats, *cap = jax.device_get(drained)
+        sims = stats[:, 0, :nv].view(np.float32)                 # (L, nv)
+        hits = stats[:, 1, :nv] > 0
+        slots = stats[:, 2, :nv]
         for p, s_row, h_row in zip(pend, sims, hits):
             li = p[0]
             st.n_layer_attempts += int(s_row.shape[0])
@@ -1339,12 +1369,10 @@ class MemoEngine:
             st.sims.extend(s_row.tolist())
         if hits.any():
             out.reuse_slots = slots[hits]
-        if prep.capture and len(pend[0]) > 4:
-            embs = np.asarray(jnp.stack([p[4] for p in pend]))[:, :nv]
-            apms = np.asarray(jnp.stack([p[5] for p in pend]))[:, :nv]
-            # prefill capture stages the KV plane at pend[6]
-            kvs = (np.asarray(jnp.stack([p[6] for p in pend]))[:, :nv]
-                   if len(pend[0]) > 6 else None)
+        if cap:
+            embs, apms = cap[0][:, :nv], cap[1][:, :nv]
+            # prefill capture stages the KV plane as a third block
+            kvs = cap[2][:, :nv] if len(cap) > 2 else None
             lens = None if prep.lengths is None else prep.lengths[:nv]
             for l in range(embs.shape[0]):
                 miss = ~hits[l]

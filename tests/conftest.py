@@ -5,11 +5,16 @@ slice of its API (``given`` / ``settings`` / three strategies), so when
 the real package is missing we register a deterministic shim in
 ``sys.modules`` before collection. Seeded sampling keeps the property
 tests meaningful (many examples per test) and reproducible.
+
+``check_drain_parity`` holds the engine's stats drain to the eager
+formula it replaced (``tests/test_runtime.py``, ``tests/test_prefill.py``).
 """
 from __future__ import annotations
 
 import sys
 import types
+
+import pytest
 
 
 def _install_hypothesis_shim():
@@ -85,3 +90,77 @@ try:  # pragma: no cover - exercised implicitly at collection time
     import hypothesis  # noqa: F401
 except ImportError:
     _install_hypothesis_shim()
+
+
+def _eager_drain(eng, prep):
+    """The eager stats drain that ``memo_drain`` replaced, kept as the
+    parity reference: a per-layer ``astype`` and ``jnp.stack``, a stack
+    across layers and one across the slots, blocking reads, then the host
+    fold. Returns the fields ``finalize`` must reproduce."""
+    import jax.numpy as jnp
+    import numpy as np
+    pend, nv = prep.pend, prep.n_valid
+    payload = np.asarray(jnp.stack(
+        [jnp.stack([p[1], p[2].astype(jnp.float32)]) for p in pend]))
+    slots = np.asarray(jnp.stack([p[3] for p in pend]))[:, :nv]
+    hits = payload[:, 1, :nv] > 0.5
+    sims = payload[:, 0, :nv]
+    admissions = []
+    if prep.capture and len(pend[0]) > 4:
+        embs = np.asarray(jnp.stack([p[4] for p in pend]))[:, :nv]
+        apms = np.asarray(jnp.stack([p[5] for p in pend]))[:, :nv]
+        kvs = (np.asarray(jnp.stack([p[6] for p in pend]))[:, :nv]
+               if len(pend[0]) > 6 else None)
+        lens = None if prep.lengths is None else prep.lengths[:nv]
+        for l in range(embs.shape[0]):
+            miss = ~hits[l]
+            if miss.any():
+                admissions.append(eng._stage_capture(
+                    apms[l][miss], embs[l][miss],
+                    None if lens is None else lens[miss],
+                    None if kvs is None else kvs[l][miss]))
+    return {"n_hits": int(hits.sum()), "n_layer_attempts": int(hits.size),
+            "per_layer_hits": {p[0]: int(h.sum())
+                               for p, h in zip(pend, hits)},
+            "sims": sims.reshape(-1),
+            "reuse_slots": slots[hits] if hits.any() else None,
+            "admissions": admissions}
+
+
+def _check_drain_parity(eng, prep):
+    """``eng.finalize(prep)`` against the eager drain on the same staged
+    device stats: counters equal, sims bit for bit, reuse slots and
+    admissions equal in value and dtype. Returns finalize's
+    ``(outputs, stats, payload)``."""
+    import numpy as np
+    from repro.core.engine import MemoStats
+    ref = _eager_drain(eng, prep)
+    out, st, payload = eng.finalize(prep, stats=MemoStats())
+    assert st.n_hits == ref["n_hits"]
+    assert st.n_layer_attempts == ref["n_layer_attempts"]
+    assert st.per_layer_hits == ref["per_layer_hits"]
+    got = np.asarray(list(st.sims), np.float32)
+    np.testing.assert_array_equal(got.view(np.int32),
+                                  ref["sims"].view(np.int32))
+    if ref["reuse_slots"] is None:
+        assert payload.reuse_slots is None
+    else:
+        assert payload.reuse_slots.dtype == ref["reuse_slots"].dtype
+        np.testing.assert_array_equal(payload.reuse_slots,
+                                      ref["reuse_slots"])
+    assert len(payload.admissions) == len(ref["admissions"])
+    for got_adm, ref_adm in zip(payload.admissions, ref["admissions"]):
+        for g, r in zip(got_adm, ref_adm):
+            if r is None:
+                assert g is None
+            else:
+                assert g.dtype == r.dtype
+                np.testing.assert_array_equal(g, r)
+    return out, st, payload
+
+
+@pytest.fixture
+def check_drain_parity():
+    """``check_drain_parity(eng, prep)`` finalizes a prepared, run batch
+    and asserts its drained stats equal the eager reference drain's."""
+    return _check_drain_parity

@@ -210,6 +210,32 @@ def test_prefill_miss_matches_exact(pf_engine):
     assert dmax <= 2e-3 and agree == 1.0
 
 
+def test_prefill_memo_drain_matches_eager_drain(pf_engine,
+                                               check_drain_parity):
+    """A capturing prefill batch at a threshold that mixes hits and
+    misses: the jitted drain gives the eager drain's counters, sims (bit
+    for bit), reuse slots and KV-bearing admissions."""
+    eng, _, corpus, _ = pf_engine
+    batch = {"tokens": jnp.asarray(
+        corpus.sample(4, np.random.default_rng(23))[0])}
+    probe = eng.prepare_batch(batch, threshold=1e9, prefill=True)
+    eng.run_layers(probe)
+    _, st0, _ = eng.finalize(probe)
+    thr = float(np.median(list(st0.sims)))
+    admit0 = eng.mc.admit, eng.mc.admit_every
+    eng.mc.admit, eng.mc.admit_every = True, 1
+    try:
+        prep = eng.prepare_batch(batch, threshold=thr, prefill=True)
+        assert prep.capture
+        eng.run_layers(prep)
+        _, st, payload = check_drain_parity(eng, prep)
+    finally:
+        eng.mc.admit, eng.mc.admit_every = admit0
+    assert 0 < st.n_hits < st.n_layer_attempts
+    assert payload.admissions
+    assert all(adm[3] is not None for adm in payload.admissions)
+
+
 def test_prefill_length_gate(pf_engine):
     """Stored entries were captured at SEQ; a shorter prompt may NEVER
     replay them even when the threshold passes everything — the length

@@ -143,6 +143,23 @@ def test_varlen_admission_learns_new_lengths(vl_engine):
 
 # ------------------------------------------------- runtime invariants
 
+class _Counting:
+    """A module stand-in that counts calls to the ``counted`` names."""
+
+    def __init__(self, real, counted):
+        self._real, self.counts = real, {n: 0 for n in counted}
+        for n in counted:
+            def mk(name, fn=getattr(real, n)):
+                def f(*a, **k):
+                    self.counts[name] += 1
+                    return fn(*a, **k)
+                return f
+            setattr(self, n, mk(n))
+
+    def __getattr__(self, name):
+        return getattr(self._real, name)
+
+
 def test_runtime_zero_per_layer_host_sync(vl_engine, monkeypatch):
     """One batch through MemoServer.step issues exactly ONE
     block_until_ready and at most the two stacked stats transfers —
@@ -158,20 +175,6 @@ def test_runtime_zero_per_layer_host_sync(vl_engine, monkeypatch):
     for ln in (SEQ, SEQ - 2, SEQ, SEQ):
         server.submit(np.asarray(corpus.sample(1)[0][0, :ln]))
 
-    class _Counting:
-        def __init__(self, real, counted):
-            self._real, self.counts = real, {n: 0 for n in counted}
-            for n in counted:
-                def mk(name, fn=getattr(real, n)):
-                    def f(*a, **k):
-                        self.counts[name] += 1
-                        return fn(*a, **k)
-                    return f
-                setattr(self, n, mk(n))
-
-        def __getattr__(self, name):
-            return getattr(self._real, name)
-
     fake_jax = _Counting(jax, ["block_until_ready"])
     fake_np = _Counting(np, ["asarray", "nonzero"])
     monkeypatch.setattr(engine_mod, "jax", fake_jax)
@@ -182,6 +185,107 @@ def test_runtime_zero_per_layer_host_sync(vl_engine, monkeypatch):
     assert fake_np.counts["asarray"] <= 2
     assert fake_np.counts["nonzero"] == 0
     server.close()
+
+
+def test_drain_issues_no_eager_programs(vl_engine, monkeypatch):
+    """The stats drain of a MemoServer step runs no eager jnp.stack or
+    astype: one jitted ``memo_drain`` (cached per batch shape) packs the
+    stats, and one block_until_ready stays the only barrier."""
+    eng, corpus = vl_engine
+    server = MemoServer(eng, buckets=(SEQ,), max_batch=4,
+                        async_maintenance=False)
+    for key in [k for k in eng._jit_cache if k[0] == "drain"]:
+        del eng._jit_cache[key]
+
+    def drain_keys():
+        return {k for k in eng._jit_cache if k[0] == "drain"}
+
+    calls = {"stack": 0, "astype": 0}
+
+    class _Jnp:
+        def __getattr__(self, name):
+            return getattr(jnp, name)
+
+        def stack(self, *a, **k):
+            calls["stack"] += 1
+            return jnp.stack(*a, **k)
+
+    array_t = type(jnp.zeros(1))
+    real_astype = array_t.astype
+
+    def counting_astype(self, *a, **k):
+        calls["astype"] += 1
+        return real_astype(self, *a, **k)
+
+    real_drain = eng._drain_stats
+
+    def counted_drain(*a, **k):
+        with monkeypatch.context() as m:
+            m.setattr(engine_mod, "jnp", _Jnp())
+            m.setattr(array_t, "astype", counting_astype)
+            return real_drain(*a, **k)
+
+    monkeypatch.setattr(eng, "_drain_stats", counted_drain)
+    fake_jax = None
+    for n_new in (1, 0):     # first batch of a shape, then the second
+        before = drain_keys()
+        for _ in range(4):
+            server.submit(np.asarray(corpus.sample(1)[0][0]))
+        if n_new == 0:
+            fake_jax = _Counting(jax, ["block_until_ready"])
+            fake_np = _Counting(np, ["asarray"])
+            monkeypatch.setattr(engine_mod, "jax", fake_jax)
+            monkeypatch.setattr(engine_mod, "np", fake_np)
+        assert len(server.step(flush=True)) == 4
+        new = drain_keys() - before
+        assert len(new) == n_new
+        assert all(eng._jit_cache[k].__name__ == "memo_drain" for k in new)
+    assert calls == {"stack": 0, "astype": 0}
+    assert fake_jax.counts["block_until_ready"] == 1
+    assert fake_np.counts["asarray"] <= 2
+    server.close()
+
+
+def _mixed_threshold(eng, batch, can_hit):
+    """A threshold that splits the lookups of ``can_hit`` rows into hits
+    and misses: the median of their similarities on this batch."""
+    prep = eng.prepare_batch(batch, threshold=1e9)
+    eng.run_layers(prep)
+    _, st, _ = eng.finalize(prep)
+    sims = np.asarray(list(st.sims)).reshape(len(prep.pend), -1)
+    return float(np.median(sims[:, can_hit]))
+
+
+@pytest.mark.parametrize("case", ["fixed", "varlen", "mixed", "capture"])
+def test_memo_drain_matches_eager_drain(vl_engine, check_drain_parity,
+                                        case):
+    """The jitted drain gives the MemoStats counters, sims (bit for bit),
+    reuse slots and admissions of the eager drain it replaced: on a
+    fixed-length batch, a padded variable-length one with n_valid < rows,
+    at a threshold that mixes hits and misses, and under capture."""
+    eng, corpus = vl_engine
+    lens = [SEQ, SEQ, SEQ - 2, SEQ]
+    toks, lens_np = _varlen_batch(corpus, lens, SEQ)
+    batch = {"tokens": jnp.asarray(toks)}
+    if case in ("varlen", "capture"):
+        batch.update(lengths=lens_np, n_valid=3)
+    thr = 0.6
+    if case in ("mixed", "capture"):
+        thr = _mixed_threshold(eng, batch, [0, 1])
+    admit0 = eng.mc.admit, eng.mc.admit_every
+    eng.mc.admit, eng.mc.admit_every = case == "capture", 1
+    try:
+        prep = eng.prepare_batch(batch, threshold=thr)
+        assert prep.capture == (case == "capture")
+        eng.run_layers(prep)
+        _, st, payload = check_drain_parity(eng, prep)
+    finally:
+        eng.mc.admit, eng.mc.admit_every = admit0
+    assert st.n_layer_attempts == len(eng.layers) * prep.n_valid
+    if case in ("mixed", "capture"):
+        assert 0 < st.n_hits < st.n_layer_attempts
+    if case == "capture":
+        assert payload.admissions and payload.reuse_slots.size
 
 
 def test_runtime_bounded_jit_shape_set(vl_engine):
