@@ -2,11 +2,16 @@
 steps ``MemoServer`` until every request of the window has completed.
 
 Latency is charged from the scheduled arrival (``submit(arrival=...)``),
-so a stall delays every later request. The driver keeps its own host
-spans, and under a trace also writes them into the profiler's trace as
-``TraceAnnotation`` events (``bench.submit``, ``bench.step``,
-``bench.wait``), so device idle time can be attributed to what the host
-was doing.
+so a stall delays every later request. A classification request's latency
+ends where the server stamps it (``Completion.latency``); a prefill
+request's ends when the ``step()`` that served it returns, since the
+server stamps it before ``memo.complete`` cuts each request's decode
+caches out of the batch, and those caches are the prefill's answer.
+
+The driver keeps its own host spans, and under a trace also writes them
+into the profiler's trace as ``TraceAnnotation`` events (``bench.submit``,
+``bench.step``, ``bench.wait``), so device idle time can be attributed to
+what the host was doing.
 """
 from __future__ import annotations
 
@@ -25,7 +30,7 @@ class Served:
     rid: int
     arrival: float          # scheduled, seconds after the window opened
     done: float             # completion, same clock
-    latency: float          # server's completion - scheduled arrival
+    latency: float          # completion - scheduled arrival
     step_start: float       # start of the step() span that served it
     tokens: int
     answer: int
@@ -56,10 +61,11 @@ def _span(name: str, on: bool):
 
 def run(server, reqs, seconds: float, answer: Callable, *,
         on_complete: Optional[Callable] = None, annotate: bool = False,
-        drain_s: float = 60.0) -> Window:
-    """Serve ``reqs`` (sorted by arrival) open-loop. ``answer(comp)`` is
-    read from each completion as it arrives; ``on_complete(rid, comp)``
-    may keep what the output check needs and must drop the rest."""
+        drain_s: float = 60.0, prefill: bool = False) -> Window:
+    """Serve ``reqs`` (sorted by arrival) open-loop, each submitted with
+    ``prefill``. ``answer(comp)`` is read from each completion as it
+    arrives; ``on_complete(rid, comp)`` may keep what the output check
+    needs and must drop the rest."""
     win = Window(seconds=seconds, attempted=len(reqs))
     base = server._now()
     sleep_cap = server.max_delay
@@ -71,7 +77,8 @@ def run(server, reqs, seconds: float, answer: Callable, *,
             with _span("bench.submit", annotate):
                 while i < n and reqs[i].arrival <= now:
                     r = reqs[i]
-                    rid = server.submit(r.tokens, arrival=base + r.arrival)
+                    rid = server.submit(r.tokens, arrival=base + r.arrival,
+                                        prefill=prefill)
                     rid_of[rid] = i
                     win.lateness.append(now - r.arrival)
                     i += 1
@@ -88,9 +95,10 @@ def run(server, reqs, seconds: float, answer: Callable, *,
                               st.n_hits - h0, st.n_layer_attempts - a0))
             for c in got:
                 k = rid_of.pop(c.rid)
+                lat = t_e - reqs[k].arrival if prefill else c.latency
                 win.served[k] = Served(
                     rid=k, arrival=reqs[k].arrival,
-                    done=c.latency + reqs[k].arrival, latency=c.latency,
+                    done=lat + reqs[k].arrival, latency=lat,
                     step_start=t_s, tokens=int(c.length),
                     answer=answer(c))
                 if on_complete is not None:

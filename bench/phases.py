@@ -3,13 +3,14 @@
     python3 bench/phases.py --workload bert_base_rope_allmiss.templ_r80 \\
         --seed 7 --seconds 30
 
-Runs what ``bench/run.py --trace 1`` runs, with the trace fold extended by
-``spanfold`` (the program's ``memo.*`` spans and the device's "XLA
-Modules" line). It prints ``run.py``'s traced result line, in which
-``breakdown`` names idle gaps by the program's spans and device
-operations by their program, with one more entry, ``program``: the
-readings of ``spanfold.READINGS`` and ``idle_by_phase``, each left out
-where the program recorded no span. The seven per-layer metrics read the
+Runs what ``bench/run.py --trace 1`` runs (its traced window already
+holds ``spanfold``'s keys: the program's ``memo.*`` spans and the
+device's "XLA Modules" line), with the breakdown read by ``spanfold``. It
+prints ``run.py``'s traced result line, in which ``breakdown`` names
+idle gaps by the program's spans and device operations by their program,
+with one more entry, ``program``: the readings of ``spanfold.READINGS``
+and ``idle_by_phase``, each left out where the program recorded no
+span. The seven per-layer metrics read the
 same numbers as under ``run.py``: the keys they read are unchanged.
 """
 from __future__ import annotations
@@ -25,21 +26,19 @@ ROOT = Path(__file__).resolve().parents[1]
 
 @contextlib.contextmanager
 def folding():
-    """Inside, ``tracefold.load`` also folds ``spanfold.fold`` and keeps
-    the last trace it loaded in the dict this yields, under ``"trace"``;
-    the breakdown's ``top_ops`` and ``idle_gaps`` are spanfold's."""
+    """Inside, ``tracefold.load`` keeps the last trace it loaded in the
+    dict this yields, under ``"trace"``; the breakdown's ``top_ops`` and
+    ``idle_gaps`` are spanfold's."""
     from bench import spanfold, tracefold
     kept: dict = {}
     saved = tracefold.load, tracefold.top_ops, tracefold.idle_gaps
     load = saved[0]
 
-    def load_all(path):
-        tr = load(path)
-        tr.update(spanfold.fold(path))
-        kept["trace"] = tr
+    def load_kept(path):
+        kept["trace"] = tr = load(path)
         return tr
 
-    tracefold.load = load_all
+    tracefold.load = load_kept
     tracefold.top_ops, tracefold.idle_gaps = (spanfold.top_ops,
                                               spanfold.idle_gaps)
     try:

@@ -1,5 +1,6 @@
-"""Plain reference of the pre-norm rotary encoder that the
-``bert_base_rope*`` configurations run, in straightforward ``jax.numpy``.
+"""Plain reference of the pre-norm rotary transformer that the
+``bert_base_rope*`` (encoder) and ``gpt2_small_rope*`` (causal decoder)
+configurations run, in straightforward ``jax.numpy``.
 
 It imports nothing of the program. It reads the weights the benchmark made
 (``weights.py``) by their names in the program's parameter layout: layers
@@ -7,9 +8,11 @@ stacked along a leading axis under ``params["layers"]["seg0"]["l0"]``.
 
 Each layer is ``h += attn(norm1(h)); h += mlp(norm2(h))`` with layer
 norms (eps 1e-6), rotary positions on queries and keys (rotate-half form,
-theta ``rope_theta``), softmax attention over all keys, and a two-matrix
-MLP with biases and the tanh form of GELU. The classifier mean-pools the
-final-normed hidden states.
+theta ``rope_theta``), softmax attention over all keys (over the keys at or
+before the query's position where the configuration is ``causal``), and a
+two-matrix MLP with biases and the tanh form of GELU. The classifier
+mean-pools the final-normed hidden states; the language-model head is the
+transposed token embedding (tied), read at the positions asked for.
 
 ``quant="int8"`` is the control: every linear layer computes with
 symmetric int8 weights (one scale per output column) and int8 inputs (one
@@ -80,6 +83,9 @@ def attention(x, p, cfg, quant):
     rep = H // Hkv
     k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(dh)
+    if cfg["causal"]:
+        keep = jnp.arange(S)[None, :] <= jnp.arange(S)[:, None]
+        scores = jnp.where(keep, scores, -jnp.inf)
     apm = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", apm, v).reshape(B, S, H * dh)
     return linear(out, p["wo"].reshape(H * dh, D), quant)
@@ -92,11 +98,11 @@ def mlp(x, p, quant):
 
 def hidden(params, tokens, cfg, quant=None):
     """Final-normed hidden states (B, S, D) of token ids (B, S)."""
-    if cfg["norm"] != "layernorm" or cfg["glu"] or cfg["causal"] \
+    if cfg["norm"] != "layernorm" or cfg["glu"] \
             or cfg.get("qkv_bias") or cfg.get("qk_norm") \
             or cfg.get("mixer", "gqa") != "gqa":
-        raise ValueError("this reference covers the bidirectional "
-                         "layer-norm, plain-MLP GQA encoder only")
+        raise ValueError("this reference covers the layer-norm, "
+                         "plain-MLP GQA transformer only")
     stack = params["layers"]["seg0"]["l0"]
     h = params["embed"][tokens].astype(jnp.float32)
     for i in range(cfg["n_layers"]):
@@ -111,3 +117,12 @@ def classify(params, tokens, cfg, quant=None):
     pooled = jnp.mean(hidden(params, tokens, cfg, quant), axis=1)
     return linear(pooled, params["cls"], quant)
 
+
+
+def lm_logits(params, tokens, cfg, positions, quant=None):
+    """Logits (B, len(positions), vocab) of the tied language-model head at
+    the given positions of token ids (B, S)."""
+    if not cfg["tie_embeddings"]:
+        raise ValueError("this reference covers the tied LM head only")
+    h = hidden(params, tokens, cfg, quant)[:, jnp.asarray(positions)]
+    return linear(h, params["embed"].astype(jnp.float32).T, quant)

@@ -140,8 +140,9 @@ def time_steps(fn, batches, reps: int = 1) -> float:
 def memo_speedup(sess, sess_cfg, task, reqs, rows: int):
     """(memo-off, served) seconds per padded batch, on the same batches of
     the window's own prompts: the served leg is the engine's
-    prepare_batch -> run_layers -> finalize, the memo-off leg the task's
-    plain model."""
+    prepare_batch -> run_layers -> finalize (with ``prefill`` where the
+    task is prefill, so the leg returns the decode caches too), the
+    memo-off leg the task's plain model."""
     import jax.numpy as jnp
     import numpy as np
     eng = sess.engine
@@ -151,10 +152,12 @@ def memo_speedup(sess, sess_cfg, task, reqs, rows: int):
     batches = [jnp.asarray(pad_batch(full[i * rows:(i + 1) * rows], rows))
                for i in range(n_b)]
     lens = np.full((rows,), S, np.int32)
+    prefill = is_prefill(task)
 
     def served(tokens):
         prep = eng.prepare_batch({"tokens": tokens, "lengths": lens,
-                                  "n_valid": rows}, sync_store=False)
+                                  "n_valid": rows}, sync_store=False,
+                                 prefill=prefill)
         eng.run_layers(prep)
         return eng.finalize(prep)[0]
 
@@ -163,10 +166,16 @@ def memo_speedup(sess, sess_cfg, task, reqs, rows: int):
     return time_steps(off, batches, reps), time_steps(served, batches, reps)
 
 
-def agreement(off, reqs, win, rows: int, seed: int,
+def is_prefill(task) -> bool:
+    """Whether the task's requests are served as prefill (``PREFILL``)."""
+    return bool(getattr(task, "PREFILL", False))
+
+
+def agreement(off, logits_of: Callable, reqs, win, rows: int, seed: int,
               cap: int = 4096) -> float:
     """Share of served requests (all, or a seeded sample of ``cap``) whose
-    answer equals the program's memo-off answer on the same prompt."""
+    answer equals the program's memo-off answer on the same prompt;
+    ``logits_of`` takes the answer logits from what ``off`` returns."""
     import jax.numpy as jnp
     import numpy as np
     ks = sorted(win.served)
@@ -180,8 +189,8 @@ def agreement(off, reqs, win, rows: int, seed: int,
     for group in by_len.values():
         for i in range(0, len(group), rows):
             part = group[i:i + rows]
-            out = np.asarray(off(jnp.asarray(pad_batch(
-                [reqs[k].tokens for k in part], rows))))
+            out = np.asarray(logits_of(off(jnp.asarray(pad_batch(
+                [reqs[k].tokens for k in part], rows)))))
             want = np.argmax(out[: len(part)], -1)
             same += int(sum(int(w) == win.served[k].answer
                             for w, k in zip(want, part)))
@@ -192,7 +201,10 @@ def output_check(cfg, task, ref, model, params, reqs, kept,
                  control: bool = False) -> dict:
     """Compares what the window served for the sampled requests with the
     plain reference, run in float32 under HIGHEST precision once over each
-    prompt with its served answer.
+    prompt with its served answer. ``kept`` is emptied: the task turns
+    what it kept into served tokens and logit rows first (a prefill task
+    decodes through the served caches), so the program's arrays are freed
+    before the reference runs.
 
     Per served answer: ``gap``, by how much the served token's reference
     logit lies below the reference's best; and ``err``, the largest
@@ -205,7 +217,7 @@ def output_check(cfg, task, ref, model, params, reqs, kept,
     import numpy as np
     keys = sorted(kept)
     prompts = [reqs[k].tokens for k in keys]
-    served = task.served([kept[k] for k in keys])
+    served = task.served([kept.pop(k) for k in keys], model, params, cfg)
     mcfg = cfg["model"]
     f32 = jax.jit(lambda p, t, s: task.reference_rows(ref, p, mcfg, t, s))
     q8 = jax.jit(lambda p, t, s: task.reference_rows(ref, p, mcfg, t, s,
@@ -287,6 +299,7 @@ class Cell:
         self.cfg = cfg = registry.config(cell["config"], spec, root)
         self.traffic = traffic = registry.traffic(cell["traffic"], bench)
         self.task = task = registry.module("tasks", cfg["task"], bench)
+        self.prefill = is_prefill(task)
         self.ref = registry.module("references", cfg["reference"], bench)
 
         import jax
@@ -332,7 +345,8 @@ class Cell:
             max_delay=float(sv["max_delay_ms"]) * 1e-3)
         warm = self.requests(1.0, dict(traffic, arrivals="backlog",
                                        count=2 * self.rows + 1), stream=1)
-        driver.run(self.server, warm, 0.0, task.answer)
+        driver.run(self.server, warm, 0.0, task.answer,
+                   prefill=self.prefill)
         self.server.drain_maintenance(timeout=120)
         gc.collect()
         t_wu = time.perf_counter()
@@ -356,16 +370,23 @@ class Cell:
     def window(self, reqs, seconds: float, sample=(), trace: bool = False):
         """Drive ``reqs`` open-loop, with the garbage collector held off.
         Returns (window, counters, kept, trace or None): ``kept`` holds
-        what the output check needs of the sampled requests."""
+        what the output check needs of the sampled requests. A traced
+        window's trace also holds ``spanfold.fold``'s keys. Sets
+        ``peaks_seen``: the device's peak bytes at the window's open and just
+        before the first sampled completion is kept, so the peak of
+        serving alone is known apart from what the check holds."""
         import jax
-        from bench import driver, tracefold
+        from bench import driver, spanfold, tracefold
         server, task = self.server, self.task
         st = server.stats
         kept: dict = {}
         sample = set(sample)
+        self.peaks_seen = {"open": self.peak_bytes()}
 
         def on_complete(k, comp):
             if k in sample:
+                if not kept:
+                    self.peaks_seen["before_sample"] = self.peak_bytes()
                 kept[k] = task.keep(comp)
 
         before = (st.n_hits, st.n_layer_attempts, server.n_batches,
@@ -383,14 +404,17 @@ class Cell:
         gc.disable()
         try:
             win = driver.run(server, reqs, seconds, task.answer,
-                             on_complete=on_complete, annotate=trace)
+                             on_complete=on_complete, annotate=trace,
+                             prefill=self.prefill)
         finally:
             gc.enable()
             gc.unfreeze()
             self.compiles.on = False
         if trace:
             jax.profiler.stop_trace()
-            tr = tracefold.load(tracefold.find_xplane(tdir))
+            xplane = tracefold.find_xplane(tdir)
+            tr = tracefold.load(xplane)
+            tr.update(spanfold.fold(xplane))
             shutil.rmtree(tdir, ignore_errors=True)
         server.drain_maintenance(timeout=120)
         n_b = server.n_batches - before[2]
@@ -440,7 +464,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         len(reqs), n_sample, replace=False).tolist()
     win, counters, kept, tr = c.window(reqs, seconds, sample, trace)
     peak = c.peak_bytes()
-    log(f"[memory] peak_bytes_in_use {peak}")
+    log(f"[memory] peak_bytes_in_use {peak}; at the window's open "
+        f"{c.peaks_seen['open']}, before the first kept sample "
+        f"{c.peaks_seen.get('before_sample', peak)}")
     speed = None
     if trace:
         speed = memo_speedup(c.sess, cfg, task, reqs, rows)
@@ -449,10 +475,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     c.release()
 
     t_c = time.perf_counter()
-    agree = agreement(task.memo_off(c.model, c.params, cfg), reqs, win,
-                      rows, seed)
+    agree = agreement(task.memo_off(c.model, c.params, cfg),
+                      task.logits_of, reqs, win, rows, seed)
     out = output_check(cfg, task, c.ref, c.model, c.params, reqs, kept)
-    kept.clear()
     gaps = out["gap"]
     limits = cfg["check"]["limits"]
     numbers = check_numbers(gaps, out["err"])

@@ -8,7 +8,8 @@ holding ``memo.assemble``, ``memo.prepare``, ``memo.run_layers`` (one
 maintenance payload. ``tracefold.load`` folds none of them, nor the
 device's "XLA Modules" line that names the program each operation ran in.
 ``fold`` reads both from the same ``.xplane.pb`` into two more keys of
-the trace form:
+the trace form, which ``run.Cell.window`` merges into every traced
+window's trace:
 
 - ``program_spans``: ``[name, start_ns, end_ns, line, args]``, where
   ``line`` numbers the host thread that recorded the span and ``args``

@@ -14,6 +14,11 @@ def memo_off(model, params, cfg: dict):
     return lambda tokens: fn(params, tokens)
 
 
+def logits_of(out):
+    """The answer logits (B, n_classes) of what ``memo_off`` returns."""
+    return out
+
+
 def answer(comp) -> int:
     return int(np.argmax(comp.logits))
 
@@ -23,8 +28,10 @@ def keep(comp):
     return np.asarray(comp.logits, np.float32)
 
 
-def served(kept) -> list:
-    """Per request, (served tokens, served logit rows (1, n_classes))."""
+def served(kept, *program) -> list:
+    """Per request, (served tokens, served logit rows (1, n_classes)).
+    ``program`` (model, params, configuration) goes unused: the kept
+    logits are the whole answer."""
     return [([int(np.argmax(k))], k[None]) for k in kept]
 
 

@@ -4,7 +4,7 @@ order, maintenance on its own thread, and the readings of ``spanfold``
 and ``bench/phases.py`` taken from them."""
 import pytest
 
-from bench import phases, run, spanfold
+from bench import phases, run, spanfold, tracefold
 from bench.tests import tiny
 
 SEED = 2**31 + 11
@@ -37,6 +37,18 @@ def test_one_step_span_per_batch(traced):
     rows = [(st[4]["n_valid"], st[4]["bucket"]) for st, _ in steps]
     assert rows == [(w[2], w[3]) for w in win.steps]
     assert all(st[4]["rows"] >= st[4]["n_valid"] for st, _ in steps)
+
+
+def test_window_trace_holds_program_spans(traced):
+    """A traced window's trace holds the program's memo.* spans under
+    ``program_spans``, one memo.complete per batch served, and the
+    driver's bench.* spans alone under ``spans``."""
+    _, counters, tr = traced
+    assert {sp[0] for sp in tr["spans"]} <= {
+        "bench.submit", "bench.step", "bench.wait"}
+    assert tracefold.spans_of(tr, "bench.step")
+    done = [sp for sp in tr["program_spans"] if sp[0] == "memo.complete"]
+    assert len(done) == counters["n_batches"]
 
 
 def test_each_step_holds_its_phases_in_order(traced):

@@ -126,3 +126,51 @@ def test_recorded_trace():
     gaps = tracefold.idle_gaps(tr, lo, hi)
     assert gaps and all(name == "none" for name, _ in gaps)
     assert sum(g for _, g in gaps) <= (hi - lo) * 1e-9 - busy + 1e-9
+
+
+READERS = ("queue_wait_ms", "step_ms", "hit_rate", "memo_speedup",
+           "memo_attention_roofline", "step_mfu", "step_device_idle")
+
+
+def _spans(lo, hi):
+    """Two driver steps over the recorded slice, with the program's spans
+    inside them (``spanfold.fold``'s form: serving thread 1) and a
+    maintenance span on thread 2 across the gap between them."""
+    mid = (lo + hi) / 2
+    bench = [["bench.step", lo, mid], ["bench.wait", mid, mid + MS],
+             ["bench.step", mid + MS, hi]]
+    memo = []
+    for s, e in ((lo, mid), (mid + MS, hi)):
+        memo += [["memo.step", s + 1000, e - 1000, 1, {}],
+                 ["memo.layer", s + 2000, s + 3000, 1, {}],
+                 ["memo.complete", e - 0.4 * MS, e - 1000, 1, {}]]
+    memo.append(["memo.maintain", mid - MS, mid + 2 * MS, 2, {}])
+    return bench, memo
+
+
+def test_program_spans_change_no_reader():
+    """With the program's memo.* spans folded in beside the driver's, the
+    seven readers and the breakdown read what they read on the driver's
+    spans alone; complete_ms reads the memo.complete spans, and nothing
+    without them."""
+    tr = json.loads(DATA.read_text())
+    evs = tr["devices"]["/device:TPU:0"]
+    lo, hi = min(e[1] for e in evs), max(e[2] for e in evs)
+    bench, memo = _spans(lo, hi)
+    before = dict(tr, spans=sorted(bench, key=lambda s: s[1]))
+    after = dict(before, modules={},
+                 program_spans=sorted(memo, key=lambda s: (s[1], -s[2])))
+    got = {}
+    for name, t in (("before", before), ("after", after)):
+        ctx = _ctx(t, speedup=(1.0, 4.0))
+        got[name] = {r: registry.module("metrics", r).read(ctx)
+                     for r in READERS}
+        got[name]["breakdown"] = (tracefold.top_ops(t),
+                                  tracefold.idle_gaps(t, lo, hi))
+        got[name]["complete_ms"] = registry.module(
+            "metrics", "complete_ms").read(ctx)
+    assert all(got["before"][r] is not None for r in READERS)
+    assert got["before"]["complete_ms"] is None
+    assert got["after"].pop("complete_ms") == pytest.approx(0.4 - 1e-3)
+    got["before"].pop("complete_ms")
+    assert got["after"] == got["before"]

@@ -34,10 +34,28 @@ def test_model_step(S):
         + 4 * S * S * 768
     assert w.flops(BERT, S, "classify") == 12 * per_layer + 2 * 768 * 2
     with pytest.raises(ValueError):
-        w.flops(BERT, S, "lm_last")
+        w.flops(BERT, S, "pooled_max")
     if S == 512:
         # ~0.75 TFLOP per 8-row batch of 512 tokens (issue reckoning)
         assert 0.7e12 < 8 * w.flops(BERT, S, "classify") < 0.8e12
+
+
+GPT2 = dict(BERT, vocab=50257, causal=True, n_classes=0)
+
+
+def test_model_step_lm_last():
+    """Causal prefill of one 512-token prompt with the last position's
+    logits: per layer 2*512*(4*768^2 + 2*768*3072) for the matrices and
+    half of 4*512^2*768 for attention, then 2*768*50257 for the head."""
+    w = registry.module("work", "model_step")
+    per_layer = 2 * 512 * 7077888 + 402653184
+    assert per_layer == 7650410496
+    head = 2 * 768 * 50257
+    assert head == 77194752
+    assert w.flops(GPT2, 512, "lm_last") == 12 * per_layer + head \
+        == 91882120704
+    # ~0.74 TFLOP per 8-row batch of 512-token prompts
+    assert 0.7e12 < 8 * w.flops(GPT2, 512, "lm_last") < 0.75e12
 
 
 def test_peaks_table_has_the_v5e():

@@ -1,7 +1,8 @@
 """A copy of the benchmark at a size the CPU runs in seconds: the real
-harness files, the cell's configuration cut to two narrow layers, and its
+harness files, each cell's configuration cut to two narrow layers, and its
 traffic mix at 32 tokens, under a temporary root with its own
-BENCHMARK.json. The check keeps the cell's own limits."""
+BENCHMARK.json. The check keeps each cell's own limits. A cell is named
+as in BENCHMARK.json; ``name`` gives its tiny copy's name."""
 from __future__ import annotations
 
 import copy
@@ -15,39 +16,68 @@ ROOT = BENCH.parent
 TINY_MODEL = {"n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 4,
               "d_ff": 128, "vocab": 512}
 SEQ = 32
-CELL = json.loads((ROOT / "BENCHMARK.json").read_text())["workloads"][0]
-ENCODER = f"tiny_{CELL['config']}.tiny_{CELL['traffic']}"
+
+
+def spec() -> dict:
+    return json.loads((ROOT / "BENCHMARK.json").read_text())
+
+
+def cell(workload: str) -> dict:
+    for w in spec()["workloads"]:
+        if w["name"] == workload:
+            return w
+    raise KeyError(f"no workload {workload!r} in BENCHMARK.json")
+
+
+def config(workload: str) -> dict:
+    """The cell's configuration file, as the chip runs it."""
+    return json.loads((BENCH / "configs" / f"{cell(workload)['config']}"
+                       ".json").read_text())
+
+
+def name(workload: str) -> str:
+    """The tiny copy's name of a cell of BENCHMARK.json."""
+    w = cell(workload)
+    return f"tiny_{w['config']}.tiny_{w['traffic']}"
+
+
+ENCODER = name("bert_base_rope_allmiss.templ_r80")
+DECODER = name("gpt2_small_rope_allmiss.prefill_templ_r80")
 
 
 def make(tmp: Path) -> Path:
-    """Lay the tiny benchmark out under ``tmp``; returns its root."""
+    """Lay the tiny copy of every cell out under ``tmp``; returns its
+    root."""
     root = Path(tmp)
     shutil.copytree(BENCH, root / "bench",
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
-    spec = copy.deepcopy(json.loads((ROOT / "BENCHMARK.json").read_text()))
-    cfg = json.loads((BENCH / "configs" / f"{CELL['config']}.json")
-                     .read_text())
-    cfg["name"] = f"tiny_{CELL['config']}"
-    cfg["model"].update(TINY_MODEL)
-    cfg["calibration"] = {"sequences": 16, "batch": 8}
-    cfg["serve"]["buckets"] = [SEQ]
-    cfg["check"]["sample"] = 8
-    cfg["memo"]["embed_steps"] = 20
-    (root / "bench" / "configs" / f"{cfg['name']}.json").write_text(
-        json.dumps(cfg))
-    tr = json.loads((BENCH / "traffic" / f"{CELL['traffic']}.json")
-                    .read_text())
-    tr.update(length=SEQ, rate_per_s=40)
-    (root / "bench" / "traffic" / f"tiny_{CELL['traffic']}.json").write_text(
-        json.dumps(tr))
-    spec["configs"] = [{"name": cfg["name"], "source": "test",
-                        "file": f"bench/configs/{cfg['name']}.json",
-                        "reduced": [], "why": "test"}]
-    spec["workloads"] = [{"name": ENCODER, "config": cfg["name"],
-                          "traffic": f"tiny_{CELL['traffic']}", "chips": 1,
-                          "why": "test"}]
-    for m in spec["per_layer"]:
+    out = copy.deepcopy(spec())
+    configs, workloads = {}, []
+    for w in out["workloads"]:
+        cfg = config(w["name"])
+        cfg["name"] = f"tiny_{w['config']}"
+        cfg["model"].update(TINY_MODEL)
+        cfg["calibration"] = {"sequences": 16, "batch": 8}
+        cfg["serve"]["buckets"] = [SEQ]
+        cfg["check"]["sample"] = 8
+        cfg["memo"]["embed_steps"] = 20
+        (root / "bench" / "configs" / f"{cfg['name']}.json").write_text(
+            json.dumps(cfg))
+        configs[cfg["name"]] = {"name": cfg["name"], "source": "test",
+                                "file": f"bench/configs/{cfg['name']}.json",
+                                "reduced": [], "why": "test"}
+        tr = json.loads((BENCH / "traffic" / f"{w['traffic']}.json")
+                        .read_text())
+        tr.update(length=SEQ, rate_per_s=40)
+        (root / "bench" / "traffic" / f"tiny_{w['traffic']}.json"
+         ).write_text(json.dumps(tr))
+        workloads.append({"name": name(w["name"]), "config": cfg["name"],
+                          "traffic": f"tiny_{w['traffic']}", "chips": 1,
+                          "why": "test"})
+    out["configs"] = list(configs.values())
+    out["workloads"] = workloads
+    for m in out["end_to_end"] + out["per_layer"]:
         if "workloads" in m:
-            m["workloads"] = [ENCODER]
-    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+            m["workloads"] = [name(w) for w in m["workloads"]]
+    (root / "BENCHMARK.json").write_text(json.dumps(out))
     return root
