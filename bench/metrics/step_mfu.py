@@ -3,6 +3,8 @@ operations of the requests served in the window, at their own lengths,
 over the time inside the driver's step() spans in the trace times the
 peak. Bounds every kernel's roofline from above in its effect on
 latency."""
+from collections import Counter
+
 from bench import tracefold
 
 
@@ -14,6 +16,7 @@ def read(ctx):
     if t <= 0:
         return None
     work = ctx.work("model_step")
-    ops = sum(work.flops(ctx.model, s.tokens, ctx.config["head"])
-              for s in ctx.window.served.values())
+    lengths = Counter(s.tokens for s in ctx.window.served.values())
+    ops = sum(n * work.flops(ctx.model, S, ctx.config["head"], ctx.shapes)
+              for S, n in lengths.items())
     return 100.0 * ops / (t * ctx.peaks["bf16_flops_per_s"])

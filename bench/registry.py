@@ -9,8 +9,10 @@ none that exist.
 """
 from __future__ import annotations
 
+import dataclasses
 import importlib.util
 import json
+import typing
 from pathlib import Path
 
 BENCH = Path(__file__).resolve().parent
@@ -39,6 +41,43 @@ def config(name: str, spec: dict, root: Path = ROOT) -> dict:
         if c["name"] == name:
             return load_json(Path(root) / c["file"])
     raise KeyError(f"no configuration {name!r} in BENCHMARK.json")
+
+
+def model_config(model: dict):
+    """The program's ``ModelConfig`` from a configuration's ``model`` dict,
+    built by the dataclass's declared field types: a nested dict becomes
+    its dataclass (``MLAConfig``, ``MoEConfig``, ``EncoderConfig``, any
+    other such field), a list a tuple. A key the dataclass does not
+    declare is an error that names it."""
+    from repro.configs.base import ModelConfig
+    return _build(ModelConfig, model, "model")
+
+
+def _build(cls, fields: dict, where: str):
+    hints = typing.get_type_hints(cls)
+    unknown = sorted(set(fields) - {f.name for f in dataclasses.fields(cls)})
+    if unknown:
+        raise KeyError(f"{where}: {cls.__name__} has no field "
+                       f"{', '.join(unknown)}")
+    return cls(**{k: _value(hints[k], v, f"{where}.{k}")
+                  for k, v in fields.items()})
+
+
+def _value(tp, v, where: str):
+    if typing.get_origin(tp) is typing.Union:           # Optional[...]
+        tp = next(a for a in typing.get_args(tp) if a is not type(None))
+    if isinstance(v, dict) and dataclasses.is_dataclass(tp):
+        return _build(tp, v, where)
+    if isinstance(v, list):
+        return tuple(v)
+    return v
+
+
+def program(model: dict):
+    """The program's model for a configuration's ``model`` dict, with its
+    layers unrolled, as the benchmark serves it."""
+    from repro.models import build_model
+    return build_model(model_config(model), layer_loop="unroll")
 
 
 def traffic(name: str, bench: Path = BENCH) -> dict:

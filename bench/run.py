@@ -107,6 +107,7 @@ class Context:
     speedup: Optional[tuple] = None
     log: Callable = log
     work: Callable = None
+    shapes: Optional[dict] = None
 
 
 def memo_spec(cfg: dict):
@@ -318,13 +319,13 @@ class Cell:
         log(f"[device] {dev.platform} {dev.device_kind} x{len(self.devs)}; "
             f"jax {jax.__version__}; compile cache {cache_dir}")
 
-        from repro.configs.base import ModelConfig
         from repro.memo import MemoSession
-        from repro.models import build_model
 
         mcfg = cfg["model"]
-        self.model = build_model(ModelConfig(**mcfg), layer_loop="unroll")
-        self.params = jax.block_until_ready(weights.make(self.model, seed))
+        self.model = registry.program(mcfg)
+        self.shapes = weights.shapes_of(self.model)
+        self.params = jax.block_until_ready(
+            weights.make(self.model, seed, self.shapes))
         t_w = time.perf_counter()
         self.templates = generator.corpus(traffic, mcfg["vocab"], seed)
         cal = cfg["calibration"]
@@ -519,7 +520,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         ctx = Context(config=cfg, model=cfg["model"],
                       peaks=c.peaks, window=win, counters=counters,
                       store=c.store, trace=tr, speedup=speed,
-                      work=lambda n: registry.module("work", n, c.bench))
+                      work=lambda n: registry.module("work", n, c.bench),
+                      shapes=c.shapes)
         metrics = {}
         for m in registry.metrics_of(workload, c.spec, True):
             v = registry.module("metrics", m["name"], c.bench).read(ctx)

@@ -59,9 +59,10 @@ def test_top_ops_and_idle_gaps():
 
 
 def _ctx(tr, **kw):
-    model = {"n_layers": 12, "d_model": 768, "n_heads": 12,
-             "n_kv_heads": 12, "d_ff": 3072, "vocab": 30522,
-             "causal": False, "n_classes": 2}
+    model = {"name": "bert", "arch_type": "dense", "n_layers": 12,
+             "d_model": 768, "n_heads": 12, "n_kv_heads": 12, "d_ff": 3072,
+             "vocab": 30522, "causal": False, "n_classes": 2, "glu": False,
+             "norm": "layernorm", "act": "gelu"}
     served = {i: SimpleNamespace(tokens=512, step_start=0.0, arrival=0.0)
               for i in range(8)}
     c = dict(trace=tr, model=model, config={"head": "classify"},
@@ -74,7 +75,7 @@ def _ctx(tr, **kw):
              store={"S": 512, "codec": "int8", "n_entries": 768,
                     "embed_dim": 128},
              log=lambda m: None,
-             work=lambda n: registry.module("work", n))
+             work=lambda n: registry.module("work", n), shapes=None)
     c.update(kw)
     return SimpleNamespace(**c)
 

@@ -4,8 +4,12 @@ import pytest
 
 from bench import registry
 
-BERT = {"n_layers": 12, "d_model": 768, "n_heads": 12, "n_kv_heads": 12,
-        "d_ff": 3072, "vocab": 30522, "causal": False, "n_classes": 2}
+# whole model dicts: the step's operations are counted from the shapes the
+# program declares for them (a plain, ungated MLP)
+BERT = {"name": "bert", "arch_type": "dense", "n_layers": 12,
+        "d_model": 768, "n_heads": 12, "n_kv_heads": 12, "d_ff": 3072,
+        "vocab": 30522, "causal": False, "n_classes": 2, "glu": False,
+        "norm": "layernorm", "act": "gelu"}
 
 
 def test_memo_attention_hit_and_miss_rows():

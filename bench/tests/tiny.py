@@ -1,6 +1,6 @@
 """A copy of the benchmark at a size the CPU runs in seconds: the real
-harness files, each cell's configuration cut to two narrow layers, and its
-traffic mix at 32 tokens, under a temporary root with its own
+harness files, each cell's configuration cut to narrow layers (``cut``),
+and its traffic mix at 32 tokens, under a temporary root with its own
 BENCHMARK.json. The check keeps each cell's own limits. A cell is named
 as in BENCHMARK.json; ``name`` gives its tiny copy's name."""
 from __future__ import annotations
@@ -16,6 +16,44 @@ ROOT = BENCH.parent
 TINY_MODEL = {"n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 4,
               "d_ff": 128, "vocab": 512}
 SEQ = 32
+RANK, HEAD = 64, 32          # latent ranks; nope, rope, v and head widths
+EXPERTS, TOP_K, EXPERT_FF = 4, 2, 128
+
+
+def cut(model: dict) -> dict:
+    """A configuration's ``model`` dict at a CPU size that keeps every kind
+    of layer: ``TINY_MODEL``'s widths, fewer KV heads than query heads
+    where the model groups them, one leading dense layer where there are
+    any, a whole period of the layer pattern, and nested groups cut too
+    (latent ranks to ``RANK``, head widths to ``HEAD``; experts to
+    ``EXPERTS``, top-k to ``TOP_K``, their width to ``EXPERT_FF``; a
+    window under ``SEQ``)."""
+    out = dict(model, **TINY_MODEL)
+    out["n_kv_heads"] = max(1, TINY_MODEL["n_heads"] * model["n_kv_heads"]
+                            // model["n_heads"])
+    if model.get("d_head"):
+        out["d_head"] = min(model["d_head"], HEAD)
+    pattern = model.get("layer_pattern", ["mix"])
+    first = min(model.get("dense_first_n", 0), 1)
+    if "dense_first_n" in model:
+        out["dense_first_n"] = first
+    out["n_layers"] = max(TINY_MODEL["n_layers"],
+                          first + (1 if list(pattern) == ["mix"]
+                                   else len(pattern)))
+    if model.get("dense_d_ff"):
+        out["dense_d_ff"] = min(model["dense_d_ff"], EXPERT_FF)
+    if model.get("sliding_window"):
+        out["sliding_window"] = min(model["sliding_window"], SEQ // 2)
+    if model.get("mla"):
+        out["mla"] = {k: v and min(v, RANK if k.endswith("_rank") else HEAD)
+                      for k, v in model["mla"].items()}
+    if model.get("moe"):
+        m = dict(model["moe"])
+        m["n_experts"] = min(m["n_experts"], EXPERTS)
+        m["top_k"] = min(m["top_k"], TOP_K, m["n_experts"])
+        m["d_ff"] = min(m["d_ff"], EXPERT_FF)
+        out["moe"] = m
+    return out
 
 
 def spec() -> dict:
@@ -56,7 +94,7 @@ def make(tmp: Path) -> Path:
     for w in out["workloads"]:
         cfg = config(w["name"])
         cfg["name"] = f"tiny_{w['config']}"
-        cfg["model"].update(TINY_MODEL)
+        cfg["model"] = cut(cfg["model"])
         cfg["calibration"] = {"sequences": 16, "batch": 8}
         cfg["serve"]["buckets"] = [SEQ]
         cfg["check"]["sample"] = 8
