@@ -1313,6 +1313,29 @@ class MemoEngine:
                     lambda *a: jnp.stack(a), *groups)
         return caches
 
+    def split_cache_rows(self, caches) -> tuple:
+        """Cut a batch's decode caches (the segment pytree ``finalize`` or
+        ``prefill_exact`` returns) into one pytree per batch row, row i's
+        in the layout ``model.decode_step`` takes. ONE jitted program,
+        ``memo_split_caches``, cached per cache tree and leaf shapes (so
+        per padded row count): a batch's fill never retraces it, and the
+        caller drops filler rows' trees. Every leaf is sliced the same
+        way, in single and scan segments alike (slicing the merged tree
+        directly would hit scan segments' leading reps axis instead of
+        the batch axis)."""
+        leaves, tree = jax.tree.flatten(caches)
+        key = ("split", tree, tuple((a.shape, a.dtype) for a in leaves))
+        fn = self._jit_cache.get(key)
+        if fn is None:
+            def memo_split_caches(caches):
+                by_li = self._split_caches(caches)
+                rows = jax.tree.leaves(by_li)[0].shape[0]
+                return tuple(self._merge_caches({
+                    li: jax.tree.map(lambda a, i=i: a[i: i + 1], c)
+                    for li, c in by_li.items()}) for i in range(rows))
+            fn = self._jit_cache[key] = jax.jit(memo_split_caches)
+        return fn(caches)
+
     def _memo_drain(self, prep: PreparedBatch):
         """Dispatch ``memo_drain``: ONE jitted program that packs the
         per-layer device stats of ``prep.pend`` into one int32 block

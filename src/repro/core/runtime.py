@@ -340,25 +340,21 @@ class MemoServer:
         self.stats.merge(st)
         self.n_batches += 1
         done = self._now()
-        with span("complete"):
+        with span("complete", requests=n, rows=rows):
             comps = []
             if prefill:
                 logits_all, caches = out
+                # per-request decode caches: one program cuts every row
+                # out of the batch; filler rows' trees are dropped here,
+                # and each answer is on the device when step() returns
+                per_row = eng.split_cache_rows(caches)[:n]
                 out_np = np.asarray(logits_all)          # (rows, vocab)
-                by_li = eng._split_caches(caches)
+                per_row = jax.block_until_ready(per_row)
                 for i, r in enumerate(reqs):
-                    # per-request decode caches: slice batch row i out of
-                    # every cache leaf, then re-merge into the segment
-                    # pytree model.decode_step consumes (slicing the merged
-                    # tree directly would hit scan segments' leading reps
-                    # axis instead of the batch axis)
-                    c_i = eng._merge_caches({
-                        li: jax.tree.map(lambda a, i=i: a[i: i + 1], c)
-                        for li, c in by_li.items()})
                     comps.append(Completion(
                         rid=r.rid, logits=out_np[i], latency=done - r.arrival,
                         length=int(r.tokens.size), bucket=bucket,
-                        batch_rows=n, caches=c_i))
+                        batch_rows=n, caches=per_row[i]))
                 return comps
             out_np = np.asarray(out)
             for i, r in enumerate(reqs):
@@ -646,7 +642,8 @@ class MemoServer:
         combination — with ``admit_every > 1`` the fused jit has BOTH a
         capturing and a non-capturing variant per shape, and serving
         alternates between them, so both must be compiled here or a
-        mid-trace XLA compile lands in the p99. Maintenance payloads are
+        mid-trace XLA compile lands in the p99. A prefill batch also
+        compiles its ``memo_split_caches``. Maintenance payloads are
         dropped and counters rolled back, so warm-up leaves the store
         untouched."""
         sizes = list(batch_sizes) if batch_sizes is not None else None
@@ -677,7 +674,10 @@ class MemoServer:
                             prep = eng.prepare_batch(batch, prefill=pf,
                                                      sync_store=False)
                             eng.run_layers(prep)
-                            eng.finalize(prep, stats=MemoStats())
+                            out, _, _ = eng.finalize(prep,
+                                                     stats=MemoStats())
+                            if pf:
+                                eng.split_cache_rows(out[1])
         finally:
             eng._serve_batches = serve_counter
 

@@ -9,7 +9,8 @@ against exact prefill inside the kernel-parity bounds, the miss path
 matching exact prefill, the causal and length-equality hit gates, and
 the prefill-only admission-capture gate; MemoServer prefill serving
 (per-request cache slices, plain/prefill mixing, the MEMO_DISABLED
-exact fallback); session save/load round-tripping the KV arenas; and
+exact fallback, the one split program bitwise against eager slices and
+compiled per padded row count); session save/load round-tripping the KV arenas; and
 the backbone's own prefill+decode == full-forward parity across MHA,
 GQA-grouped, and sliding-window attention (RoPE offsets ride the
 position bookkeeping in all three).
@@ -30,6 +31,7 @@ from repro.core.prefill import PrefillCodec, stack_kv, unstack_kv_rows
 from repro.core.runtime import Health, MemoServer
 from repro.data import TemplateCorpus
 from repro.memo import MemoSession, MemoSpec, MemoStats
+from repro.models import backbone as bb
 from repro.models import build_model
 
 SEQ = 16
@@ -47,11 +49,18 @@ BOUNDS = {
 }
 
 
-@functools.lru_cache(maxsize=3)
-def _built(codec: str):
-    """Prefill-enabled session over the reduced causal GPT-2, cached per
-    codec (module-level: several tests share the int8 build)."""
-    cfg = get_reduced("gpt2_small")
+# config overrides by the decode caches' segments: the reduced GPT-2's
+# layers are one scan segment; a leading dense layer puts a single-layer
+# segment ahead of the scan
+SEGMENTS = {"scan": {}, "single": {"n_layers": 3, "dense_first_n": 1}}
+
+
+@functools.lru_cache(maxsize=4)
+def _built(codec: str, **over):
+    """Prefill-enabled session over the reduced causal GPT-2 (``over``
+    replaces config fields), cached per codec and overrides
+    (module-level: several tests share the int8 build)."""
+    cfg = get_reduced("gpt2_small").replace(**over)
     model = build_model(cfg, layer_loop="unroll")
     params = model.init(jax.random.PRNGKey(0))
     corpus = TemplateCorpus(vocab=cfg.vocab, seq_len=SEQ, n_templates=8,
@@ -360,6 +369,93 @@ def test_server_prefill_memo_disabled_falls_back_exact(pf_engine):
                                        atol=1e-5)
     finally:
         srv.close()
+
+
+@pytest.mark.parametrize("exact", [False, True], ids=["memo", "exact"])
+@pytest.mark.parametrize("segments", sorted(SEGMENTS))
+def test_server_prefill_split_matches_eager_slices(segments, exact,
+                                                   monkeypatch):
+    """Each ``Completion.caches`` is, bitwise and leaf by leaf, batch row i
+    of the step's decode caches cut by eager slices (the reference
+    ``test_server_prefill_serving`` builds as ``ce_i``): batches of 1, 3
+    and 4 requests at ``max_batch=4`` (filler rows dropped), on the memo
+    path and the MEMO_DISABLED exact path, over scan and single
+    segments."""
+    sess, _, _, calib = _built("int8", **SEGMENTS[segments])
+    eng = sess.engine
+    assert {seg.kind for seg in bb.scan_plan(eng.cfg)} == (
+        {"scan"} if segments == "scan" else {"single", "scan"})
+    leg = "prefill_exact" if exact else "finalize"
+    real, batch_caches = getattr(eng, leg), []
+
+    def spy(*a, **k):
+        out = real(*a, **k)
+        batch_caches.append(out[1] if exact else out[0][1])
+        return out
+    monkeypatch.setattr(eng, leg, spy)
+    srv = MemoServer(eng, buckets=(SEQ,), max_batch=4,
+                     async_maintenance=False)
+    if exact:
+        srv.health = Health.MEMO_DISABLED
+    cal = np.asarray(calib[0])
+    try:
+        for n in (1, 3, 4):
+            rids = [srv.submit(cal[i], prefill=True) for i in range(n)]
+            comps = srv.step(flush=True)
+            assert [c.rid for c in comps] == rids
+            by_li = eng._split_caches(batch_caches[-1])
+            for i, c in enumerate(comps):
+                ref = eng._merge_caches(
+                    {li: jax.tree.map(lambda a, i=i: a[i: i + 1], cc)
+                     for li, cc in by_li.items()})
+                assert jax.tree.structure(c.caches) == \
+                    jax.tree.structure(ref)
+                for got, want in zip(jax.tree.leaves(c.caches),
+                                     jax.tree.leaves(ref)):
+                    assert got.dtype == want.dtype
+                    np.testing.assert_array_equal(np.asarray(got),
+                                                  np.asarray(want))
+    finally:
+        srv.close()
+    assert len(batch_caches) == 3
+    assert srv.n_filler_rows == 3 + 1 + 0
+
+
+def test_server_prefill_split_compiles_per_padded_rows(pf_engine,
+                                                       monkeypatch):
+    """The split program is keyed on the padded row count, not on the
+    number of requests: ``warmup`` compiles ONE ``memo_split_caches``
+    entry, prefill batches of every size 1…max_batch at that row padding
+    trace nothing more, and ``_execute`` merges no cache per request (a
+    step's one eager ``_merge_caches`` is ``finalize``'s)."""
+    eng, _, _, calib = pf_engine
+    for key in [k for k in eng._jit_cache if k[0] == "split"]:
+        del eng._jit_cache[key]
+    srv = MemoServer(eng, buckets=(SEQ,), max_batch=4, batch_quantum=4,
+                     async_maintenance=False)
+    srv.warmup(batch_sizes=[4])
+    keys = [k for k in eng._jit_cache if k[0] == "split"]
+    # one scan segment: every leaf is (reps, rows, ...)
+    assert len(keys) == 1 and {s[1] for s, _ in keys[0][2]} == {4}
+    calls = {"eager": 0, "traced": 0}
+    merge = eng._merge_caches
+
+    def counting(by_li):
+        traced = any(isinstance(a, jax.core.Tracer)
+                     for a in jax.tree.leaves(by_li))
+        calls["traced" if traced else "eager"] += 1
+        return merge(by_li)
+    monkeypatch.setattr(eng, "_merge_caches", counting)
+    cal = np.asarray(calib[0])
+    try:
+        for n in range(1, 5):
+            for i in range(n):
+                srv.submit(cal[i], prefill=True)
+            assert len(srv.step(flush=True)) == n
+    finally:
+        srv.close()
+    assert [k for k in eng._jit_cache if k[0] == "split"] == keys
+    assert calls == {"eager": 4, "traced": 0}
 
 
 # ---------------------------------------------------------- session layer

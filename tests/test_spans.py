@@ -23,7 +23,7 @@ from repro.core.spans import span
 SEQ = 16
 # jit-cache keys of the programs MemoServer and its memo-off legs run
 SERVING_KEYS = {"prolog", "fused", "fusedpf", "plain", "plainpf", "head",
-                "headpf", "pfexact"}
+                "headpf", "pfexact", "split"}
 
 
 def _recorded(tmp_path, fn):
@@ -212,6 +212,8 @@ def test_prefill_programs_and_spans(tmp_path):
         "memo.step", "memo.assemble", "memo.prepare", "memo.run_layers",
         "memo.layer", "memo.layer", "memo.barrier", "memo.drain",
         "memo.maintain", "memo.complete"]
+    assert next(e for e in ev if e[0] == "memo.complete")[4] == {
+        "requests": 2, "rows": 4}
     assert {"memo_prolog", "memo_layer_prefill", "memo_layer_plain_prefill",
-            "memo_head_prefill", "memo_prefill_exact"} <= \
-        _program_names(eng)
+            "memo_head_prefill", "memo_prefill_exact",
+            "memo_split_caches"} <= _program_names(eng)
